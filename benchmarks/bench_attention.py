@@ -17,6 +17,7 @@ from repro.config import GRConfig
 from repro.configs import get_config
 from repro.core.xattention import paged_beam_attention, staged_beam_attention
 from repro.baselines.paged import kv_token_bytes, separated_read_bytes
+from repro.launch.compile_cache import enable_compile_cache
 
 HBM_BW = 819e9
 
@@ -56,4 +57,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

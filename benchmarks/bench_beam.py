@@ -24,6 +24,7 @@ from repro.core import ItemTrie
 from repro.core.xbeam import (BeamState, beam_step, host_beam_select,
                               naive_beam_select, sparse_beam_step)
 from repro.data import gen_catalog
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def fig11(record):
@@ -164,4 +165,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

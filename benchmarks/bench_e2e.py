@@ -30,12 +30,14 @@ vs on — rid-matched warm-request TTFT, token-weighted hit rate, and the
 prefill tokens the cache skipped (``experiments/bench/``).
 
 Plus the ISSUE-7 sharded scenario: the same traffic swept over
-(replicas, model_axis) replica-fleet shapes on 8 forced host devices —
-each config routes submits across ``replicas`` data-parallel engines, each
-tensor-parallel over a ``model_axis``-wide mesh slice.  Runs in a
-subprocess (the forced-device XLA flag must own process startup) and
-records per-config p99/throughput plus per-replica occupancy to
-``experiments/bench/e2e_sharded.json``.
+(replicas, model_axis) replica-fleet shapes on the devices this process
+owns (8 forced host devices on the CPU:
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``) — each config
+routes submits across ``replicas`` data-parallel engines, each
+tensor-parallel over a ``model_axis``-wide mesh slice.  Records per-config
+p99/throughput plus per-replica occupancy to
+``experiments/bench/e2e_sharded.json``; configs that need more devices
+than are visible are listed as skipped.
 
 Batch compute is real measured CPU wall time; queueing/streams are composed
 on the simulated clock (see serving/server.py for the rationale).  The
@@ -47,8 +49,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import subprocess
-import sys
 
 import jax
 import numpy as np
@@ -60,6 +60,7 @@ from repro.core import ItemTrie
 from repro.data import gen_catalog, gen_histories, poisson_trace
 from repro.models import get_model
 from repro.serving import GREngine, make_engine, run_server
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def mixed_prefill(cfg, gr, catalog, trie, params):
@@ -264,10 +265,14 @@ def prefix_reuse(cfg, gr, catalog, trie, params):
 SHARDED_CONFIGS = ((1, 1), (2, 1), (2, 2), (4, 2))
 
 
-def sharded_worker():
-    """ISSUE 7 sweep body — runs in the forced-8-device subprocess."""
+def sharded():
+    """Replica-fleet sweep, in this process on the devices it owns.  Configs
+    that need more devices than are visible are recorded as skipped; on
+    the CPU, start the benchmark with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for all four.
+    A config that fails raises, so the benchmark exits non-zero."""
     from repro.serving import make_sharded_system, run_server as _run
-    assert len(jax.devices()) >= 8, jax.devices()
+    n_dev = len(jax.devices())
     cfg = get_config("onerec-0.1b").reduced()
     gr = GRConfig(beam_width=8, top_k=8, num_decode_phases=3,
                   num_items=500, tid_vocab=cfg.vocab_size)
@@ -276,8 +281,14 @@ def sharded_worker():
     params = get_model(cfg).init(jax.random.PRNGKey(0))
     hist = gen_histories(catalog, 40, max_tokens=96, seed=13)
     trace = poisson_trace(hist, rps=150.0, duration_s=0.3, seed=14)
-    record = {"scenario": "sharded", "requests": len(trace), "configs": []}
+    record = {"scenario": "sharded", "requests": len(trace),
+              "devices": n_dev, "configs": [], "skipped": []}
     for n, tp in SHARDED_CONFIGS:
+        if n * tp > n_dev:
+            record["skipped"].append({"replicas": n, "model_axis": tp})
+            row(f"sharded_r{n}_tp{tp}", 0.0,
+                f"skipped;needs={n * tp}_devices;visible={n_dev}")
+            continue
         scfg = ServeConfig(max_batch_tokens=4096, max_batch_requests=8,
                            batch_wait_quota_ms=5.0, num_streams=2,
                            scheduler_policy="chunked",
@@ -312,25 +323,6 @@ def sharded_worker():
     row("sharded_summary", best,
         f"p99_best_ms={best:.1f};p99_1x1_ms={base:.1f}"
         f";configs={len(record['configs'])};json={path}")
-
-
-def sharded():
-    """ISSUE 7: replica-fleet sweep in a subprocess — the forced-device
-    XLA flag must own process startup, so the sweep cannot run in the
-    parent bench process."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src"), root,
-         env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--sharded-worker"],
-        env=env, cwd=root, capture_output=True, text=True, timeout=1200)
-    sys.stdout.write(proc.stdout)           # relay the worker's CSV rows
-    if proc.returncode != 0:
-        row("sharded_FAILED", 0.0, proc.stderr.strip().replace("\n", " ")
-            [-300:])
 
 
 SCENARIOS = ("fig13", "mixed_prefill", "beam_select", "pipeline",
@@ -388,20 +380,18 @@ def main(scenarios=None, trace_out=None):
 
 
 if __name__ == "__main__":
-    if "--sharded-worker" in sys.argv:
-        sharded_worker()
-    else:
-        ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-        ap.add_argument("scenario", nargs="*", metavar="scenario",
-                        help=f"scenarios to run (default: all); "
-                             f"from: {', '.join(SCENARIOS)}")
-        ap.add_argument("--trace-out", default=None, metavar="PATH",
-                        help="write the pipeline scenario's Chrome/Perfetto "
-                             "trace JSON here (ISSUE 10 flight recorder; "
-                             "open in ui.perfetto.dev)")
-        args = ap.parse_args()
-        unknown = set(args.scenario) - set(SCENARIOS)
-        if unknown:
-            ap.error(f"unknown scenario(s) {sorted(unknown)}; "
-                     f"choose from {', '.join(SCENARIOS)}")
-        main(scenarios=args.scenario or None, trace_out=args.trace_out)
+    enable_compile_cache()
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("scenario", nargs="*", metavar="scenario",
+                    help=f"scenarios to run (default: all); "
+                         f"from: {', '.join(SCENARIOS)}")
+    ap.add_argument("--trace-out", default=None, metavar="PATH",
+                    help="write the pipeline scenario's Chrome/Perfetto "
+                         "trace JSON here (flight recorder; "
+                         "open in ui.perfetto.dev)")
+    args = ap.parse_args()
+    unknown = set(args.scenario) - set(SCENARIOS)
+    if unknown:
+        ap.error(f"unknown scenario(s) {sorted(unknown)}; "
+                 f"choose from {', '.join(SCENARIOS)}")
+    main(scenarios=args.scenario or None, trace_out=args.trace_out)

@@ -13,6 +13,7 @@ from repro.configs import get_config
 from repro.core import GRDecoder, ItemTrie
 from repro.data import gen_catalog
 from repro.models import get_model
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -42,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -20,6 +20,7 @@ import numpy as np
 
 from benchmarks.common import row, write_bench_json
 from repro.kernels.beam_attn.tune import HBM_BW, PEAK_FLOPS, cost_model
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def analyze(S, BW, H, kvH, hd, layers):
@@ -98,4 +99,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -17,6 +17,7 @@ from repro.baselines.paged import (PagedKVSimulator, separated_cache_bytes,
                                    separated_read_bytes)
 from repro.config import GRConfig
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _qwen3_4b_like():
@@ -80,4 +81,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

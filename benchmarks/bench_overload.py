@@ -41,6 +41,7 @@ from repro.core import ItemTrie
 from repro.data import gen_catalog, gen_histories
 from repro.models import get_model
 from repro.serving import ServingSystem, make_engine
+from repro.launch.compile_cache import enable_compile_cache
 
 MULTIPLIERS = (0.5, 1.0, 2.0, 4.0)
 POLICIES = ("none", "reject", "degrade")
@@ -191,6 +192,7 @@ def main(trace_out: str = None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write the 2x-saturation degrade run's Chrome/"

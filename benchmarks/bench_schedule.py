@@ -14,6 +14,7 @@ from repro.core import ItemTrie
 from repro.data import gen_catalog, gen_histories, poisson_trace
 from repro.models import get_model
 from repro.serving import GREngine, available_policies, run_server
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -67,4 +68,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

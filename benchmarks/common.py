@@ -71,8 +71,6 @@ def row(name: str, us_per_call: float, derived: str):
 
 def flops_bytes(fn, *args) -> dict:
     """cost_analysis of a jitted callable on the current (1-dev) backend."""
-    from repro.roofline.analysis import cost_analysis_dict
-    lowered = jax.jit(fn).lower(*args)
-    ca = cost_analysis_dict(lowered.compile())
+    ca = jax.jit(fn).lower(*args).compile().cost_analysis()
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0))}

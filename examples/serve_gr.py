@@ -62,6 +62,7 @@ from repro.serving import (ServingSystem, available_policies,
                            beam_pool_summary, cache_summary, engine_summary,
                            latency_summary, make_engine, make_sharded_system,
                            pipeline_summary, replica_summary, ttft_summary)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -284,4 +285,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -303,8 +303,8 @@ abstract = (init_beam_state(1, gr, abstract=True),
             sds((1, BW), jnp.int32),
             sds((L, 1, BW, ND, kvH, hd), jnp.float32),
             sds((L, 1, BW, ND, kvH, hd), jnp.float32),
-            sds((L, P, pg, kvH, hd), jnp.float32),
-            sds((L, P, pg, kvH, hd), jnp.float32),
+            sds((L, P, kvH, pg, hd), jnp.float32),
+            sds((L, P, kvH, pg, hd), jnp.float32),
             sds((1, MP), jnp.int32), sds((1,), jnp.int32))
 view = f"tensor<{L}x1x{MP * pg}x{kvH}x{hd}xf32>"
 texts = {impl: jax.jit(GRDecoder(cfg, gr, trie, impl).beam_phase_paged,

@@ -205,7 +205,7 @@ class GRDecoder:
         tokens    : (R, C) chunk tokens, right-padded
         offsets   : (R,) absolute start position of each request's chunk
         lengths   : (R,) valid tokens in this chunk (0 = request skipped)
-        pages_k/v : (L, P, pg, kvH, hd) physical page pool
+        pages_k/v : (L, P, kvH, pg, hd) physical page pool
         table     : (R, MP) int32 page tables (OOB sentinel for unmapped)
 
         Returns (logits (R, V) at each request's last valid chunk position,
@@ -214,25 +214,25 @@ class GRDecoder:
         :meth:`prefill_chunk` (see :meth:`_chunk_forward`); only the KV
         view (page-table gather) and the write target (physical pages,
         stale contents masked) differ."""
-        P, pg = pages_k.shape[1], pages_k.shape[2]
-        MP = table.shape[1]
-        S = MP * pg
+        P, pg = pages_k.shape[1], pages_k.shape[3]
         pid, pslot = page_slots(table, offsets, lengths,
                                 tokens.shape[1], pg, P)
-        ptbl = jnp.where(table < P, table, 0)                # gather indices
 
         def view(kv):
-            pk, pv = kv                                      # (P,pg,kvH,hd)
-            return (pk[ptbl].reshape(-1, S, *pk.shape[2:]),
-                    pv[ptbl].reshape(-1, S, *pv.shape[2:]))
+            pk, pv = kv                                      # (P,kvH,pg,hd)
+            return (gather_pages(pk[None], table)[0],
+                    gather_pages(pv[None], table)[0])
 
         def store(kv, k, v):
+            # advanced indices around the head slice put the (R, C) index
+            # dims first, so the update is k's own (R, C, kvH, hd)
             pk, pv = kv
-            return (pk.at[pid, pslot].set(k.astype(pk.dtype), mode="drop"),
-                    pv.at[pid, pslot].set(v.astype(pv.dtype), mode="drop"))
+            return (pk.at[pid, :, pslot].set(k.astype(pk.dtype), mode="drop"),
+                    pv.at[pid, :, pslot].set(v.astype(pv.dtype), mode="drop"))
 
         logits, (nk, nv) = self._chunk_forward(
-            params, tokens, offsets, lengths, S, (pages_k, pages_v),
+            params, tokens, offsets, lengths, table.shape[1] * pg,
+            (pages_k, pages_v),
             view=view, store=store)
         return logits, nk, nv
 
@@ -287,7 +287,7 @@ class GRDecoder:
 
         ``kv_xs`` are per-layer scanned arrays holding the shared KV in
         whatever physical form the caller keeps it — contiguous
-        (L, R, S, kvH, hd) slices or (L, P, pg, kvH, hd) arena pools —
+        (L, R, S, kvH, hd) slices or (L, P, kvH, pg, hd) arena pools —
         and ``attend(q, shared_layer_kv, uk, uv)`` computes attention
         against that form (``shared_layer_kv`` is the per-layer slice tuple
         of ``kv_xs``).  Returns (logits (R, BW, V), forked+appended
@@ -364,8 +364,8 @@ class GRDecoder:
         """One decode phase reading the shared prefix straight out of the
         arena page pool via the fused paged Pallas kernel (DESIGN.md §11).
 
-        pages_k/v : (L, P, pg, kvH, hd) physical page pool (the layer axis
-                    is scanned, so the kernel sees one (P, pg, kvH, hd)
+        pages_k/v : (L, P, kvH, pg, hd) physical page pool (the layer axis
+                    is scanned, so the kernel sees one (P, kvH, pg, hd)
                     slice per layer)
         table     : (R, MP) int32 page tables (OOB sentinel for unmapped)
         Returns (logits (R, BW, V), forked+appended unshared_k/v)."""

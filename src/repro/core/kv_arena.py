@@ -4,10 +4,12 @@ One device-resident block pool holds the prefill (shared) KV of EVERY
 in-flight request, replacing the per-request contiguous caches the chunked
 engine used to allocate.  The pool is a pair of page arrays
 
-    pages_k / pages_v : (L, P, page_tokens, kvH, hd)
+    pages_k / pages_v : (L, P, kvH, page_tokens, hd)
 
-and each request owns an ordered list of physical page ids — its **page
-table** — covering its bucketed prompt span.  Prefill chunks scatter their
+— head-major within a page, so one head's page is a (page_tokens, hd) tile
+the paged Pallas kernel can DMA whole — and each request owns an ordered
+list of physical page ids — its **page table** — covering its bucketed
+prompt span.  Prefill chunks scatter their
 KV into the owning request's pages; decode gathers the pages back into a
 contiguous ``(R, S, kvH, hd)`` view through the page table and attends over
 it with the unmodified staged/paged/kernel attention — a pure permutation of
@@ -64,18 +66,18 @@ DEFAULT_PAGE_TOKENS = 64
 def gather_pages(pages: jax.Array, table: jax.Array) -> jax.Array:
     """Contiguous shared-KV view of ``table``'s pages.
 
-    pages : (L, P, pg, kvH, hd) physical page pool
+    pages : (L, P, kvH, pg, hd) physical page pool
     table : (R, MP) int32 page table; entries >= P are unmapped (their slots
             read page 0 — callers mask by ``shared_len`` so the values are
             inert)
     returns (L, R, MP*pg, kvH, hd) — request r's logical token ``t`` sits at
     position ``t`` of the view, exactly where a contiguous cache stores it.
     """
-    L, P, pg = pages.shape[:3]
+    L, P, kvH, pg, hd = pages.shape
     R, MP = table.shape
     pt = jnp.where(table < P, table, 0)
-    g = pages[:, pt]                                 # (L, R, MP, pg, kvH, hd)
-    return g.reshape(L, R, MP * pg, *pages.shape[3:])
+    g = pages[:, pt]                                 # (L, R, MP, kvH, pg, hd)
+    return g.transpose(0, 1, 2, 4, 3, 5).reshape(L, R, MP * pg, kvH, hd)
 
 
 def page_slots(table: jax.Array, offsets: jax.Array, lengths: jax.Array,
@@ -150,12 +152,12 @@ class KVArena:
             from jax.sharding import NamedSharding
             from repro.sharding.specs import kv_pool_pspec
             self._sharding = NamedSharding(
-                mesh, kv_pool_pspec(mesh, (L, num_pages, page_tokens,
-                                           kvH, hd), head_dim=3))
+                mesh, kv_pool_pspec(mesh, (L, num_pages, kvH,
+                                           page_tokens, hd), head_dim=2))
         self.pages_k = self._place(
-            jnp.zeros((L, num_pages, page_tokens, kvH, hd), dtype))
+            jnp.zeros((L, num_pages, kvH, page_tokens, hd), dtype))
         self.pages_v = self._place(
-            jnp.zeros((L, num_pages, page_tokens, kvH, hd), dtype))
+            jnp.zeros((L, num_pages, kvH, page_tokens, hd), dtype))
         # LIFO free list: lowest ids handed out first on a fresh arena,
         # most-recently-freed first afterwards (cache-friendly reuse)
         self._free: List[int] = list(range(num_pages - 1, -1, -1))
@@ -191,7 +193,7 @@ class KVArena:
     @property
     def page_nbytes(self) -> int:
         """Device bytes one page occupies (K and V planes together)."""
-        L, _, pg, kvH, hd = self.pages_k.shape
+        L, _, kvH, pg, hd = self.pages_k.shape
         return 2 * L * pg * kvH * hd * self.pages_k.dtype.itemsize
 
     # ---------------------------------------------------------- accounting
@@ -398,7 +400,7 @@ class KVArena:
             shape = list(self.pages_k.shape)
             shape[1] = old + extra
             self._sharding = NamedSharding(
-                self.mesh, kv_pool_pspec(self.mesh, shape, head_dim=3))
+                self.mesh, kv_pool_pspec(self.mesh, shape, head_dim=2))
         self.pages_k = self._place(jnp.pad(self.pages_k, pad))
         self.pages_v = self._place(jnp.pad(self.pages_v, pad))
         self._free[:0] = list(range(old + extra - 1, old - 1, -1))

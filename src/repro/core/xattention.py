@@ -114,7 +114,7 @@ def arena_beam_attention(q: jax.Array,
     the contiguous ``(R, S, kvH, hd)`` view and fed to
     :func:`staged_beam_attention`.
 
-    pages_k/v : (P, pg, kvH, hd) single-layer physical page pool
+    pages_k/v : (P, kvH, pg, hd) single-layer physical page pool
     table     : (R, MP) int32 page table; entries >= P are unmapped and
                 read page 0 — inert, because ``shared_len`` masks every
                 slot at or beyond the written frontier to an exact-zero

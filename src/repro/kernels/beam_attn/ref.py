@@ -5,7 +5,7 @@ Layout matches the kernel's pre-arranged operands (see ops.py):
   q          : (R, kvH, M, hd)   with M = BW * G   (beams-major: row b*G+g)
   shared_k/v : (R, kvH, S, hd)
   shared_len : (R,) int32
-  unshared_k/v : (R, kvH, BW, ND, hd)
+  unshared_k/v : (R, kvH, ND, M, hd) — row m holds beam m // G's key
   step       : () int32 — unshared slots 0..step are valid
   returns    : (R, kvH, M, hd) float32
 """
@@ -20,10 +20,8 @@ NEG_INF = -1e30
 
 def beam_attention_ref(q, shared_k, shared_v, shared_len,
                        unshared_k, unshared_v, step, scale: float):
-    R, kvH, M, hd = q.shape
     S = shared_k.shape[2]
-    BW, ND = unshared_k.shape[2], unshared_k.shape[3]
-    G = M // BW
+    ND = unshared_k.shape[2]
     qf = q.astype(jnp.float32)
 
     # shared stage
@@ -32,12 +30,10 @@ def beam_attention_ref(q, shared_k, shared_v, shared_len,
     s1 = jnp.where(smask, s1, NEG_INF)
 
     # unshared stage (per-beam keys)
-    qb = qf.reshape(R, kvH, BW, G, hd)
-    s2 = jnp.einsum("rhbgd,rhbnd->rhbgn", qb,
+    s2 = jnp.einsum("rhmd,rhnmd->rhmn", qf,
                     unshared_k.astype(jnp.float32)) * scale
-    umask = (jnp.arange(ND) <= step)[None, None, None, None, :]
+    umask = (jnp.arange(ND) <= step)[None, None, None, :]
     s2 = jnp.where(umask, s2, NEG_INF)
-    s2 = s2.reshape(R, kvH, M, ND)
 
     # joint softmax over S + ND columns
     m = jnp.maximum(jnp.max(s1, -1), jnp.max(s2, -1))
@@ -45,7 +41,5 @@ def beam_attention_ref(q, shared_k, shared_v, shared_len,
     p2 = jnp.exp(s2 - m[..., None])
     l = jnp.sum(p1, -1) + jnp.sum(p2, -1)
     o1 = jnp.einsum("rhms,rhsd->rhmd", p1, shared_v.astype(jnp.float32))
-    p2b = p2.reshape(R, kvH, BW, G, ND)
-    o2 = jnp.einsum("rhbgn,rhbnd->rhbgd", p2b,
-                    unshared_v.astype(jnp.float32)).reshape(R, kvH, M, hd)
+    o2 = jnp.einsum("rhmn,rhnmd->rhmd", p2, unshared_v.astype(jnp.float32))
     return (o1 + o2) / jnp.maximum(l[..., None], 1e-30)

@@ -25,9 +25,8 @@ from repro.config import INPUT_SHAPES, ShapeSpec, TrainConfig, get_shape  # noqa
 from repro.configs import ASSIGNED, get_config  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models import get_model  # noqa: E402
-from repro.roofline.analysis import (cost_analysis_dict, cost_from_compiled,  # noqa: E402
-                                     probe_pair, roofline_from_cost,
-                                     scan_corrections)
+from repro.roofline.analysis import (cost_from_compiled, probe_pair,  # noqa: E402
+                                     roofline_from_cost, scan_corrections)
 from repro.sharding import (cache_pspecs, input_pspecs, param_pspecs,  # noqa: E402
                             to_shardings)
 from repro.sharding.hints import mesh_context  # noqa: E402
@@ -116,7 +115,7 @@ def run_pair(arch: str, shape_name: str, multi_pod: bool, probe: bool,
                                   mem.temp_size_in_bytes) if v)
         rec["per_device_bytes"] = int(per_dev)
         rec["fits_16gb"] = bool(per_dev < 16e9)
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         rec["raw_cost"] = {k: float(v) for k, v in ca.items()
                            if k in ("flops", "bytes accessed")}
         rec["ok"] = True

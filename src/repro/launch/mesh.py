@@ -11,14 +11,14 @@ from typing import List, Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_axis: int = 1):
@@ -29,7 +29,8 @@ def make_host_mesh(model_axis: int = 1):
             f"model_axis={model_axis} must divide the {n} local device(s); "
             f"force more host devices with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N")
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_replica_meshes(num_replicas: int = 1, model_axis: int = 1,

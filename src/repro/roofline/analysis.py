@@ -113,20 +113,8 @@ class StepCost:
         return sum(self.collective_bytes.values())
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions.
-
-    Older jax returns a per-device list of dicts (all devices identical under
-    SPMD); newer jax returns the dict directly.  Callers always want the
-    per-device dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
 def cost_from_compiled(compiled) -> StepCost:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     txt = compiled.as_text()
     return StepCost(
         flops=float(ca.get("flops", 0.0)),

@@ -118,6 +118,23 @@ def merge_engine_stats(stats_list) -> EngineStats:
     return out
 
 
+def _check_kernel_partitioning(spec: EngineSpec, mesh) -> None:
+    """Refuse tensor parallelism through the Mosaic-compiled kernel.
+
+    GSPMD cannot partition a Pallas TPU kernel over the 'model' axis, and
+    nothing wraps the beam-attention kernel in ``shard_map`` yet.  Off the
+    TPU the kernel is interpreted into plain HLO, which GSPMD partitions."""
+    from repro.kernels.beam_attn.ops import resolve_interpret
+    tp = mesh.shape.get("model", 1) if mesh is not None else 1
+    if spec.attention_impl == "kernel" and tp > 1 \
+            and not resolve_interpret(None):
+        raise NotImplementedError(
+            f"attention_impl='kernel' with model_axis={tp}: the Pallas "
+            f"beam-attention kernel is not wrapped in shard_map, so it "
+            f"cannot be partitioned over the 'model' mesh axis on a TPU; "
+            f"use attention_impl='staged' for tensor-parallel replicas")
+
+
 @dataclasses.dataclass
 class _ChunkRuntime:
     """Per-request state for continuous (chunked) serving.
@@ -148,6 +165,9 @@ class GREngine:
                  spec: Optional[EngineSpec] = None, mesh=None):
         self.cfg = cfg
         self.mesh = mesh
+        self.spec = spec if spec is not None else \
+            EngineSpec.from_serve_config(serve_cfg, attention_impl)
+        _check_kernel_partitioning(self.spec, mesh)
         if mesh is not None:
             # Commit params to this replica's mesh slice per the TP/FSDP
             # pspec rules (DESIGN.md §10).  Committed params pull every
@@ -158,8 +178,6 @@ class GREngine:
         self.params = params
         self.trie = trie
         self.serve_cfg = serve_cfg
-        self.spec = spec if spec is not None else \
-            EngineSpec.from_serve_config(serve_cfg, attention_impl)
         if self.spec.beam_select and self.spec.beam_select != gr.beam_select:
             gr = dataclasses.replace(gr, beam_select=self.spec.beam_select)
         if getattr(serve_cfg, "beam_early_term", False) \
@@ -428,9 +446,18 @@ class GREngine:
         self.release(req.rid)
         self.stats.requests += 1
 
+    def _chunk_width(self) -> int:
+        """Padded width of every prefill chunk program: the step's whole
+        chunk budget.  One width, not a bucket per chunk length, so a
+        prompt's KV and logits do not depend on where its chunks happen to
+        split (a GEMM's rounding follows its shape), and one program per
+        page span serves every chunk."""
+        return bucket_len(max(1, self.serve_cfg.prefill_chunk_tokens),
+                          min_bucket=16)
+
     def _stage_chunk(self, e) -> Tuple[np.ndarray, int]:
-        """Pad one prefill chunk's tokens to its shape bucket."""
-        cb = bucket_len(max(e.chunk_len, 1), min_bucket=16)
+        """Pad one prefill chunk's tokens to the chunk width."""
+        cb = self._chunk_width()
         toks = np.zeros((1, cb), np.int32)
         toks[0, :e.chunk_len] = e.req.tokens[e.offset:e.offset + e.chunk_len]
         return toks, cb

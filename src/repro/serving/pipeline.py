@@ -58,7 +58,6 @@ from repro.core import xbeam
 from repro.core.item_trie import ItemTrie
 from repro.serving.engine import GREngine
 from repro.serving.request import StepPlan
-from repro.serving.scheduler import bucket_len
 
 
 def _stack_states(states) -> xbeam.BeamState:
@@ -165,7 +164,7 @@ class PipelinedEngine(GREngine):
         dispatch, so reuse first settles that dispatch (no-op when the
         lane's consumer already finished — the common case with enough
         lanes; the wait IS the double-buffer backpressure otherwise)."""
-        cb = bucket_len(max(e.chunk_len, 1), min_bucket=16)
+        cb = self._chunk_width()
         i = self._lane_rr
         self._lane_rr = (i + 1) % len(self._lanes)
         self._last_lane = i

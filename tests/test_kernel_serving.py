@@ -188,8 +188,8 @@ def test_hlo_kernel_decode_has_no_pool_gather(world):
         sds((1, BW), jnp.int32),                      # parent
         sds((L, 1, BW, ND, kvH, hd), jnp.float32),    # unshared_k
         sds((L, 1, BW, ND, kvH, hd), jnp.float32),    # unshared_v
-        sds((L, P, pg, kvH, hd), jnp.float32),        # pages_k
-        sds((L, P, pg, kvH, hd), jnp.float32),        # pages_v
+        sds((L, P, kvH, pg, hd), jnp.float32),        # pages_k
+        sds((L, P, kvH, pg, hd), jnp.float32),        # pages_v
         sds((1, MP), jnp.int32),                      # table
         sds((1,), jnp.int32),                         # shared_len
     )
@@ -202,3 +202,27 @@ def test_hlo_kernel_decode_has_no_pool_gather(world):
         ).lower(params, *abstract, d=1).as_text()
     assert view in texts["staged"]         # gather is real on the old path
     assert view not in texts["kernel"]     # and gone on the paged kernel
+
+
+@pytest.mark.parametrize("impl,interpret,tp,refused", [
+    ("kernel", False, 2, True),     # Mosaic kernel over 'model': no shard_map
+    ("kernel", True, 2, False),     # interpreted kernel is plain HLO
+    ("kernel", False, 1, False),    # one device per replica
+    ("staged", False, 2, False),
+])
+def test_tp_through_compiled_kernel_is_refused(monkeypatch, impl, interpret,
+                                               tp, refused):
+    """On a TPU the kernel is a Mosaic custom call GSPMD cannot partition:
+    an engine that would need it split over 'model' fails at construction,
+    not inside the partitioner."""
+    from jax.sharding import AbstractMesh
+    from repro.kernels.beam_attn import ops
+    from repro.serving.engine import _check_kernel_partitioning
+    monkeypatch.setattr(ops, "resolve_interpret", lambda i: interpret)
+    mesh = AbstractMesh((1, tp), ("data", "model"))
+    spec = EngineSpec(attention_impl=impl)
+    if refused:
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            _check_kernel_partitioning(spec, mesh)
+    else:
+        _check_kernel_partitioning(spec, mesh)

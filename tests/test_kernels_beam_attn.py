@@ -66,10 +66,12 @@ def test_kernel_layout_ref_agrees():
     M = BW * G
     qk = q.reshape(R, BW, kvH, G, hd).transpose(0, 2, 1, 3, 4).reshape(
         R, kvH, M, hd)
+    # unshared: (R, kvH, ND, M, hd), each beam's key repeated over G heads
+    uk_k = jnp.repeat(uk.transpose(0, 3, 2, 1, 4), G, axis=3)
+    uv_k = jnp.repeat(uv.transpose(0, 3, 2, 1, 4), G, axis=3)
     out_ref = beam_attention_ref(
         qk, sk.transpose(0, 2, 1, 3), sv.transpose(0, 2, 1, 3), slen,
-        uk.transpose(0, 3, 1, 2, 4), uv.transpose(0, 3, 1, 2, 4),
-        jnp.int32(step), 1.0 / math.sqrt(hd))
+        uk_k, uv_k, jnp.int32(step), 1.0 / math.sqrt(hd))
     out_eng = staged_beam_attention(q, sk, sv, slen, uk, uv, jnp.int32(step))
     back = np.asarray(out_ref).reshape(R, kvH, BW, G, hd).transpose(
         0, 2, 1, 3, 4).reshape(R, BW, H, hd)
@@ -174,16 +176,16 @@ def _mk_paged(rng, R, BW, H, kvH, hd, ND, pg, MP, P, slen, seed_tail_nan=False):
     uk = jnp.asarray(rng.normal(size=(R, BW, ND, kvH, hd)), jnp.float32)
     uv = jnp.asarray(rng.normal(size=(R, BW, ND, kvH, hd)), jnp.float32)
     fill = np.nan if seed_tail_nan else 1e3
-    pages_k = np.full((P, pg, kvH, hd), fill, np.float32)
-    pages_v = np.full((P, pg, kvH, hd), fill, np.float32)
+    pages_k = np.full((P, kvH, pg, hd), fill, np.float32)
+    pages_v = np.full((P, kvH, pg, hd), fill, np.float32)
     table = np.full((R, MP), P, np.int32)          # all-sentinel to start
     perm = rng.permutation(P)[: R * MP].reshape(R, MP)
     for r in range(R):
         npages = -(-int(slen[r]) // pg)            # ceil
         for j in range(npages):
             table[r, j] = perm[r, j]
-            pages_k[perm[r, j]] = rng.normal(size=(pg, kvH, hd))
-            pages_v[perm[r, j]] = rng.normal(size=(pg, kvH, hd))
+            pages_k[perm[r, j]] = rng.normal(size=(kvH, pg, hd))
+            pages_v[perm[r, j]] = rng.normal(size=(kvH, pg, hd))
     return (q, jnp.asarray(pages_k), jnp.asarray(pages_v),
             jnp.asarray(table), jnp.asarray(np.asarray(slen), jnp.int32),
             uk, uv)
@@ -221,8 +223,8 @@ def test_paged_kernel_survives_arena_growth():
         rng, R, BW, H, kvH, hd, ND, pg, MP, P, slen)
     st = jnp.int32(1)
     base = arena_beam_attention_kernel(q, pk, pv, table, slen, uk, uv, st)
-    pk2 = jnp.concatenate([pk, jnp.full((P, pg, kvH, hd), 9e9, jnp.float32)])
-    pv2 = jnp.concatenate([pv, jnp.full((P, pg, kvH, hd), 9e9, jnp.float32)])
+    pk2 = jnp.concatenate([pk, jnp.full((P, kvH, pg, hd), 9e9, jnp.float32)])
+    pv2 = jnp.concatenate([pv, jnp.full((P, kvH, pg, hd), 9e9, jnp.float32)])
     grown = arena_beam_attention_kernel(q, pk2, pv2, table, slen, uk, uv, st)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(grown))
     want = arena_beam_attention(q, pk2, pv2, table, slen, uk, uv, st)

@@ -212,7 +212,7 @@ def test_read_write_page_roundtrip():
     t = a.alloc(0, PG)
     pid = int(t[0])
     rng = np.random.default_rng(2)
-    shape = (CFG.num_layers, PG, CFG.num_kv_heads, CFG.resolved_head_dim)
+    shape = (CFG.num_layers, CFG.num_kv_heads, PG, CFG.resolved_head_dim)
     k = rng.standard_normal(shape).astype(np.float32)
     v = rng.standard_normal(shape).astype(np.float32)
     a.write_page(pid, k, v)
@@ -231,11 +231,13 @@ def _scatter_chunk(pages, table, offset, length, chunk_kv):
     """Write (C, kvH, hd) chunk KV into a single request's pages, the way
     prefill_chunk_paged does per layer."""
     C = chunk_kv.shape[0]
-    P, pg = pages.shape[1], pages.shape[2]
+    P, pg = pages.shape[1], pages.shape[3]
     pid, slot = page_slots(jnp.asarray(table)[None],
                            jnp.asarray([offset], jnp.int32),
                            jnp.asarray([length], jnp.int32), C, pg, P)
-    return pages.at[:, pid[0], slot[0]].set(chunk_kv[None], mode="drop")
+    # indices split by a slice put the index dim first: (C, L, kvH, hd)
+    return pages.at[:, pid[0], :, slot[0]].set(chunk_kv[:, None],
+                                               mode="drop")
 
 
 def test_gather_scatter_roundtrip_fragmented():
@@ -298,8 +300,8 @@ def test_arena_attention_bit_identical_to_staged():
     R, BW, ND = 2, 3, 2
     P, MP = 6, 2
     S = MP * PG
-    pages_k = rng.standard_normal((P, PG, kvH, hd)).astype(np.float32)
-    pages_v = rng.standard_normal((P, PG, kvH, hd)).astype(np.float32)
+    pages_k = rng.standard_normal((P, kvH, PG, hd)).astype(np.float32)
+    pages_v = rng.standard_normal((P, kvH, PG, hd)).astype(np.float32)
     # request 0 maps [5, 1] (reversed order), request 1 maps [2] + unmapped
     table = np.asarray([[5, 1], [2, P]], np.int32)
     slen = np.asarray([S - 3, PG - 1], np.int32)
@@ -318,8 +320,8 @@ def test_arena_attention_bit_identical_to_staged():
     for r in range(R):
         for j, p in enumerate(table[r]):
             src = 0 if p >= P else p            # unmapped slots read page 0
-            sk[r, j * PG:(j + 1) * PG] = pages_k[src]
-            sv[r, j * PG:(j + 1) * PG] = pages_v[src]
+            sk[r, j * PG:(j + 1) * PG] = pages_k[src].transpose(1, 0, 2)
+            sv[r, j * PG:(j + 1) * PG] = pages_v[src].transpose(1, 0, 2)
     ref = staged_beam_attention(jnp.asarray(q), jnp.asarray(sk),
                                 jnp.asarray(sv), jnp.asarray(slen),
                                 jnp.asarray(uk), jnp.asarray(uv), step)

@@ -151,7 +151,7 @@ def test_spill_restore_roundtrip_exact_bytes():
     table = a.alloc(0, 2 * PG)
     pid = int(table[0])
     rng = np.random.default_rng(3)
-    shape = (CFG.num_layers, PG, CFG.num_kv_heads, CFG.resolved_head_dim)
+    shape = (CFG.num_layers, CFG.num_kv_heads, PG, CFG.resolved_head_dim)
     k = rng.standard_normal(shape).astype(np.float32)
     v = rng.standard_normal(shape).astype(np.float32)
     a.write_page(pid, k, v)

@@ -17,10 +17,10 @@ from repro.sharding.specs import (_batch_spec, _mdl, cache_pspecs,
 
 CFG = get_config("onerec-0.1b").reduced()   # tiny: far below FSDP threshold
 
-TP = AbstractMesh((("data", 1), ("model", 2)))
-DP = AbstractMesh((("data", 4),))                       # no 'model' axis
-DP_TP = AbstractMesh((("data", 2), ("model", 2)))
-POD = AbstractMesh((("pod", 2), ("data", 2), ("model", 2)))
+TP = AbstractMesh((1, 2), ("data", "model"))
+DP = AbstractMesh((4,), ("data",))                       # no 'model' axis
+DP_TP = AbstractMesh((2, 2), ("data", "model"))
+POD = AbstractMesh((2, 2, 2), ("pod", "data", "model"))
 
 
 def sds(*shape):
@@ -151,13 +151,13 @@ def test_cache_falls_back_to_seq_dim():
 
 
 def test_kv_pool_pspec():
-    shape = (4, 32, 16, 4, 16)          # (L, pages, page_tokens, kvH, hd)
-    assert kv_pool_pspec(TP, shape, head_dim=3) == \
-        P(None, None, None, "model", None)
-    odd = (4, 32, 16, 3, 16)            # non-divisible heads -> replicated
-    assert kv_pool_pspec(TP, odd, head_dim=3) == P(None, None, None, None,
+    shape = (4, 32, 4, 16, 16)          # (L, pages, kvH, page_tokens, hd)
+    assert kv_pool_pspec(TP, shape, head_dim=2) == \
+        P(None, None, "model", None, None)
+    odd = (4, 32, 3, 16, 16)            # non-divisible heads -> replicated
+    assert kv_pool_pspec(TP, odd, head_dim=2) == P(None, None, None, None,
                                                    None)
-    assert kv_pool_pspec(DP, shape, head_dim=3) == P(None, None, None, None,
+    assert kv_pool_pspec(DP, shape, head_dim=2) == P(None, None, None, None,
                                                      None)
 
 
@@ -175,5 +175,5 @@ def test_input_batch_non_divisible():
 
 
 def test_input_batch_no_data_axis():
-    mesh = AbstractMesh((("model", 2),))
+    mesh = AbstractMesh((2,), ("model",))
     assert _batch_spec(mesh, 8, 2) == P(None, None)
