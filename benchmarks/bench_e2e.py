@@ -141,8 +141,8 @@ def pipeline_executors(cfg, gr, catalog, trie, params, trace_out=None):
         if trace_out is not None and executor == "pipelined":
             tr = rep.tracer
             tr.write_chrome_trace(trace_out)
-            barrier_s = sum(e.dur for e in tr.events
-                            if e.kind == "X" and e.name == "barrier")
+            barrier_s = sum(e.dur for e in tr.events if e.kind == "X"
+                            and e.name == "engine.barrier")
             stall_s = rep.pipeline["sync_stall_s"]
             record["trace"] = {
                 "path": os.path.abspath(trace_out),

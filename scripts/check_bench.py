@@ -10,8 +10,8 @@ meaningful across hosts:
   counts, decode-group counts/widths, and completion counters must match
   the baseline EXACTLY (tolerance 0).  The run also exercises the flight
   recorder (ISSUE 10 tentpole): it must produce a valid Chrome trace with
-  events, no open request spans, and barrier spans that reconcile with
-  ``sync_stall_s`` within 5%.
+  events, no open request spans, and ``engine.barrier`` spans that
+  reconcile with ``sync_stall_s`` within 5%.
 * ``overload`` — a bursty open-loop trace at 2x a calibrated service
   rate under ``shed_policy="degrade"``.  Wall-clock-dependent, so only
   DIMENSIONLESS outcomes are gated (served fraction, deadline-miss
@@ -107,8 +107,8 @@ def scenario_pipeline() -> dict:
             doc = json.loads(json.dumps(tr.to_chrome_trace(),
                                         allow_nan=False))
             assert doc["traceEvents"], "trace smoke: empty export"
-            barrier = sum(e.dur for e in tr.events
-                          if e.kind == "X" and e.name == "barrier")
+            barrier = sum(e.dur for e in tr.events if e.kind == "X"
+                          and e.name == "engine.barrier")
             stall = float(s.sync_stall_s)
             assert stall > 0 and abs(barrier - stall) <= 0.05 * stall, \
                 f"trace smoke: barrier {barrier:.4f}s vs stall {stall:.4f}s"

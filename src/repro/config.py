@@ -336,11 +336,10 @@ class ServeConfig:
     #: beam width served by a degraded request (top rows of the SAME beam
     #: state — an exact subset of the full-width selection); 0 = BW // 2
     degrade_beam_width: int = 0
-    #: flight recorder (ISSUE 10): record span/counter telemetry at every
-    #: lifecycle point into ``ServingSystem.tracer``.  Off by default —
-    #: disabled tracing is bit-identical to the uninstrumented stack, and
-    #: enabling it changes no scheduling/selection decisions (timestamps
-    #: are only read, never synced on)
+    #: flight recorder: record span/counter telemetry at every lifecycle
+    #: point into ``ServingSystem.tracer``, on the wall clock.  Off by
+    #: default; on or off, the stack makes the same device syncs and the
+    #: same scheduling/selection decisions (timestamps are only read)
     trace: bool = False
     #: ring-buffer capacity (events) of the flight recorder
     trace_capacity: int = 262144

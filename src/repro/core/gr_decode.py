@@ -99,6 +99,13 @@ class GRDecoder:
         return logits, sep
 
     # ----------------------------------------------------- staged prefill
+    # The jax.named_scope decorators below name each layer of the model
+    # step in every HLO op's ``op_name`` (so in a profiler trace too):
+    # ``prefill_chunk`` (with ``kv_gather`` / ``kv_store``),
+    # ``decode_phase`` (with ``beam_attn``) and ``beam_select`` (with
+    # ``trie_mask`` and ``topk``).  Metadata only: the values and the
+    # compiled code are the same without them.
+    @jax.named_scope("prefill_chunk")
     def _chunk_forward(self, params, tokens: jax.Array, offsets: jax.Array,
                        lengths: jax.Array, S: int, kv_xs: tuple,
                        view, store) -> Tuple[jax.Array, tuple]:
@@ -143,7 +150,8 @@ class GRDecoder:
             if cfg.rope_kind == "rope":
                 q = apply_rope(q, cos, sin, cfg.rope_fraction)
                 k = apply_rope(k, cos, sin, cfg.rope_fraction)
-            sk, sv = view(kv)
+            with jax.named_scope("kv_gather"):
+                sk, sv = view(kv)
             sk = sk.at[ridx, slot].set(k.astype(sk.dtype), mode="drop")
             sv = sv.at[ridx, slot].set(v.astype(sv.dtype), mode="drop")
             a = mha(q, sk, sv, vis, scale)
@@ -151,7 +159,9 @@ class GRDecoder:
             h = h + apply_mlp(lp["mlp"],
                               apply_norm(lp["ln2"], h, cfg.norm_kind,
                                          cfg.norm_eps), cfg.act_kind)
-            return h, store(kv, k, v)
+            with jax.named_scope("kv_store"):
+                kv = store(kv, k, v)
+            return h, kv
 
         x, ys = jax.lax.scan(layer_body, x,
                              (params["dense_layers"],) + kv_xs)
@@ -278,6 +288,7 @@ class GRDecoder:
             return beam_attention(q, sk, sv, slen, uk, uv, dstep)
         return staged_beam_attention(q, sk, sv, slen, uk, uv, dstep)
 
+    @jax.named_scope("decode_phase")
     def _decode_forward(self, params, prev_tokens: jax.Array,
                         parent: jax.Array, kv_xs: tuple, attend,
                         shared_len: jax.Array, dstep: jax.Array,
@@ -318,7 +329,8 @@ class GRDecoder:
                 uk, k[:, :, None].astype(uk.dtype), dstep, axis=2)
             uv = jax.lax.dynamic_update_slice_in_dim(
                 uv, v[:, :, None].astype(uv.dtype), dstep, axis=2)
-            a = attend(q, skv, uk, uv)
+            with jax.named_scope("beam_attn"):
+                a = attend(q, skv, uk, uv)
             h = h + dense(a.reshape(R, BW, -1), lp["attn"]["wo"])
             h = h + apply_mlp(lp["mlp"],
                               apply_norm(lp["ln2"], h, cfg.norm_kind,
@@ -384,6 +396,7 @@ class GRDecoder:
     # steps of in-flight requests with prefill chunks of arriving ones
     # (continuous batching).  Masks are device-resident (graph-mode path).
 
+    @jax.named_scope("beam_select")
     def beam_phase0(self, logits0: jax.Array
                     ) -> Tuple[xbeam.BeamState, jax.Array]:
         """First beam expansion from prefill logits (R, V) — the TTFT point:
@@ -400,6 +413,7 @@ class GRDecoder:
                  if self.trie is not None else jnp.float32(0.0))
         return xbeam.beam_step(state, logits, mask0, gr)
 
+    @jax.named_scope("beam_select")
     def _beam_select(self, state: xbeam.BeamState, logits: jax.Array,
                      d: int) -> Tuple[xbeam.BeamState, jax.Array]:
         """Phase-``d`` beam expansion over fresh decode logits: sparse

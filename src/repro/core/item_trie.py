@@ -159,6 +159,7 @@ class ItemTrie:
         int32 with CHILD_PAD padding (see ``xbeam.sparse_beam_step``)."""
         return self._dev_children[step]
 
+    @jax.named_scope("trie_mask")
     def device_masks(self, step: int, prefix_tokens: jax.Array) -> jax.Array:
         """Jittable masks: prefix_tokens (R, BW, step) int32 -> (R, BW, V).
 

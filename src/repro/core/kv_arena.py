@@ -307,7 +307,7 @@ class KVArena:
         self.stats.allocs += 1
         tr = self.tracer
         if tr is not None:
-            tr.instant("arena_alloc", tr.now(), replica=self.trace_replica,
+            tr.instant("arena_alloc", replica=self.trace_replica,
                        track="engine", rid=rid,
                        args={"pages": need, "shared": len(shared),
                              "fresh": len(fresh)})
@@ -327,7 +327,7 @@ class KVArena:
         self.stats.frees += 1
         tr = self.tracer
         if tr is not None:
-            tr.instant("arena_free", tr.now(), replica=self.trace_replica,
+            tr.instant("arena_free", replica=self.trace_replica,
                        track="engine", rid=rid,
                        args={"pages": len(table)})
             tr.gauge("arena_pages_used", self.pages_used,
@@ -407,7 +407,7 @@ class KVArena:
         self.stats.grows += 1
         tr = self.tracer
         if tr is not None:
-            tr.instant("arena_grow", tr.now(), replica=self.trace_replica,
+            tr.instant("arena_grow", replica=self.trace_replica,
                        track="engine",
                        args={"old_pages": old, "new_pages": old + extra})
             tr.count("arena_grows")

@@ -142,6 +142,7 @@ def _accumulate_pruned(state: BeamState, n: jax.Array) -> Optional[jax.Array]:
     return state.pruned + n
 
 
+@jax.named_scope("topk")
 def beam_step(state: BeamState, logits: jax.Array, mask: jax.Array,
               gr: GRConfig) -> Tuple[BeamState, jax.Array]:
     """One decode-phase beam expansion.
@@ -177,6 +178,7 @@ def beam_step(state: BeamState, logits: jax.Array, mask: jax.Array,
     return new, parent
 
 
+@jax.named_scope("topk")
 def sparse_beam_step(state: BeamState, logits: jax.Array,
                      child_tokens: jax.Array, child_ids: jax.Array,
                      gr: GRConfig) -> Tuple[BeamState, jax.Array]:

@@ -44,7 +44,7 @@ from repro.serving.engine import GREngine, merge_engine_stats
 from repro.serving.replica import Replica, ReplicaRouter
 from repro.serving.request import BatchPlan, Phase, RequestState
 from repro.serving.scheduler import SchedulerPolicy, make_policy
-from repro.serving.telemetry import Tracer
+from repro.serving.telemetry import Tracer, span
 
 
 @dataclasses.dataclass
@@ -85,10 +85,10 @@ class ServeResult:
     #: batch's engine breakdown (device_s / host_mask_s / critical_s /
     #: compile_s / dispatches) and shape (batch_size, bucket_len).
     timing: Dict[str, float] = dataclasses.field(default_factory=dict)
-    #: flight-recorder waterfall (ISSUE 10): ``[(name, t0_s, t1_s), ...]``
-    #: simulated-clock spans this request passed through (queued, prefill
-    #: chunks, decode phases, barrier waits).  None unless
-    #: ``serve_cfg.trace`` was on.
+    #: flight-recorder waterfall: ``[(name, t0_s, t1_s), ...]`` on the wall
+    #: clock (``time.perf_counter``): ``queued`` and the engine spans that
+    #: named this request (``engine.dispatch`` of its chunks and phases,
+    #: ``engine.finalize``).  None unless ``serve_cfg.trace`` was on.
     spans: Optional[List] = None
 
     @property
@@ -222,9 +222,8 @@ class ServingSystem:
         if self._continuous:
             for rep in self.replicas:
                 self._wire_continuous(rep, min_bucket)
-        # flight recorder (ISSUE 10): built only when asked for — every
-        # instrumentation site below guards on ``tracer is not None`` so
-        # the off path stays bit-identical to the uninstrumented system
+        # flight recorder: built only when asked for; without it the spans
+        # only annotate the profiler's trace and record nothing
         self.tracer: Optional[Tracer] = None
         if bool(getattr(cfg, "trace", False)):
             self.tracer = Tracer(
@@ -317,52 +316,53 @@ class ServingSystem:
                 f"simulated clock ({self._now:g}); clamping to now",
                 stacklevel=2)
             arrival_s = self._now
-        if rid is None:
-            rid = self._next_rid
-        elif rid in self._rids:
-            raise ValueError(f"duplicate rid {rid}")
-        self._rids.add(rid)
-        self._next_rid = max(self._next_rid, rid + 1)
-        eff_slo = slo_ms
-        if eff_slo is None and self._overload:
-            # admission/shedding needs a deadline to reason about; fall
-            # back to the config-wide SLO (None stays None: no deadline,
-            # never rejected, only queue-timeout shedding applies)
-            eff_slo = getattr(self.serve_cfg, "slo_ms", None)
-        deadline = arrival_s + eff_slo / 1e3 if eff_slo is not None else None
-        state = RequestState(rid, np.asarray(tokens, np.int32), arrival_s,
-                             deadline_s=deadline, tier=int(tier))
-        self.counters["submitted"] += 1
-        self._tier_count(state.tier, "submitted")
-        tr = self.tracer
-        if tr is not None:
-            tr.set_time(self._now)
-            tr.count("requests_submitted", tier=state.tier)
-            tr.request_begin(rid, arrival_s,
-                             args={"prompt_len": state.prompt_len,
-                                   "tier": state.tier})
-            tr.instant("submit", arrival_s, rid=rid,
-                       args={"prompt_len": state.prompt_len,
-                             "tier": state.tier})
-        # admission control (ISSUE 9): if the BEST predicted completion
-        # across the fleet already misses the deadline, reject now —
-        # dispatching it would only burn capacity on a guaranteed miss
-        if (self._shed_policy != "none" and deadline is not None
-                and self._predict_best(state) > deadline):
-            return self._refuse(state, "rejected", self._now)
-        # router placement (ISSUE 7): least-outstanding-tokens replica; a
-        # single-replica system trivially places everything on replica 0
-        rep = self.router.place(state)
-        rep.policy.add(state, arrival_s)
-        if not self._continuous:
-            self._shed_queued(rep, self._now)
-        # capacity-triggered dispatches (quota handled by step/drain)
-        while True:
-            plan = rep.policy.maybe_dispatch(self._now)
-            if plan is None:
-                break
-            self._dispatch(rep, plan, self._now)
-        return RequestHandle(self, state)
+        with span(self.tracer, "serving.submit", replica=None,
+                  track="serving"):
+            if rid is None:
+                rid = self._next_rid
+            elif rid in self._rids:
+                raise ValueError(f"duplicate rid {rid}")
+            self._rids.add(rid)
+            self._next_rid = max(self._next_rid, rid + 1)
+            eff_slo = slo_ms
+            if eff_slo is None and self._overload:
+                # admission/shedding needs a deadline to reason about; fall
+                # back to the config-wide SLO (None stays None: no deadline,
+                # never rejected, only queue-timeout shedding applies)
+                eff_slo = getattr(self.serve_cfg, "slo_ms", None)
+            deadline = (arrival_s + eff_slo / 1e3 if eff_slo is not None
+                        else None)
+            state = RequestState(rid, np.asarray(tokens, np.int32), arrival_s,
+                                 deadline_s=deadline, tier=int(tier))
+            self.counters["submitted"] += 1
+            self._tier_count(state.tier, "submitted")
+            tr = self.tracer
+            if tr is not None:
+                tr.count("requests_submitted", tier=state.tier)
+                tr.request_begin(rid, args={"prompt_len": state.prompt_len,
+                                            "tier": state.tier})
+                tr.instant("submit", rid=rid,
+                           args={"prompt_len": state.prompt_len,
+                                 "tier": state.tier})
+            # admission control: if the BEST predicted completion
+            # across the fleet already misses the deadline, reject now —
+            # dispatching it would only burn capacity on a guaranteed miss
+            if (self._shed_policy != "none" and deadline is not None
+                    and self._predict_best(state) > deadline):
+                return self._refuse(state, "rejected", self._now)
+            # router placement: least-outstanding-tokens replica; a
+            # single-replica system trivially places everything on replica 0
+            rep = self.router.place(state)
+            rep.policy.add(state, arrival_s)
+            if not self._continuous:
+                self._shed_queued(rep, self._now)
+            # capacity-triggered dispatches (quota handled by step/drain)
+            while True:
+                plan = rep.policy.maybe_dispatch(self._now)
+                if plan is None:
+                    break
+                self._dispatch(rep, plan, self._now)
+            return RequestHandle(self, state)
 
     # --------------------------------------------- overload control internals
     def _tier_count(self, tier: int, key: str) -> None:
@@ -418,10 +418,10 @@ class ServingSystem:
         tr = self.tracer
         if tr is not None:
             tr.count("requests_" + status, tier=state.tier)
-            tr.instant(status, t, rid=state.rid,
+            tr.instant(status, rid=state.rid,
                        args={"queued_s": t - state.arrival_s,
                              "tier": state.tier})
-            tr.request_end(state.rid, t, status)
+            tr.request_end(state.rid, status)
             tr.take_request_spans(state.rid)
         return RequestHandle(self, state)
 
@@ -493,7 +493,7 @@ class ServingSystem:
                     r.served_beam_width = min(dbw, bw) if bw else dbw
                     if self.tracer is not None:
                         self.tracer.instant(
-                            "degrade", t, replica=rep.index,
+                            "degrade", replica=rep.index,
                             track="scheduler", rid=r.rid,
                             args={"at_phase": e.decode_phase,
                                   "beam_width": r.served_beam_width})
@@ -507,7 +507,7 @@ class ServingSystem:
                     r.served_beam_width = min(dbw, bw) if bw else dbw
                     if self.tracer is not None:
                         self.tracer.instant(
-                            "degrade", t, replica=rep.index,
+                            "degrade", replica=rep.index,
                             track="scheduler", rid=r.rid,
                             args={"at_phase": 0,
                                   "beam_width": r.served_beam_width})
@@ -610,7 +610,7 @@ class ServingSystem:
                 self.counters["aborted"] += 1
                 if self.tracer is not None:
                     self.tracer.count("requests_aborted")
-                    self.tracer.request_end(rid, self._now, "aborted")
+                    self.tracer.request_end(rid, "aborted")
                     self.tracer.take_request_spans(rid)
                 return True
         return False
@@ -648,7 +648,7 @@ class ServingSystem:
                     self._aborted.add(rid)
                     self.router.settle(rid)
                     if self.tracer is not None:
-                        self.tracer.request_end(rid, self._now, "aborted")
+                        self.tracer.request_end(rid, "aborted")
                         self.tracer.take_request_spans(rid)
 
     def _earliest_deadline(self):
@@ -687,86 +687,92 @@ class ServingSystem:
                 break
             t, _, rep = min(candidates)
             tr = self.tracer
-            if tr is not None:
-                tr.set_time(t)          # engine spans start at this sim time
-            self._shed_queued(rep, t)   # dead queued work never dispatches
-            rep.policy.admit(t)
-            plan = rep.policy.plan_step(t)
+            with span(tr, "serving.plan", replica=rep.index,
+                      track="scheduler") as planned:
+                self._shed_queued(rep, t)   # dead queued work never runs
+                rep.policy.admit(t)
+                plan = rep.policy.plan_step(t)
+                if plan is not None:
+                    self._apply_degradation(rep, plan, t)
             if plan is None:        # defensive: has_work lied (foreign
                 stuck.add(rep.index)  # policy) — skip, don't spin
                 continue
-            self._apply_degradation(rep, plan, t)
             timing = rep.engine.run_step(plan)      # real measured compute
-            end = t + timing["critical_s"]
-            rep.busy_until = end
-            rep.dispatches += 1
-            rep.cost_model.observe(plan.token_cost, timing["critical_s"])
-            rep.policy.commit(plan)
-            for e in plan.entries:
-                r = e.req
-                if r.dispatch_s is None:
-                    r.dispatch_s = t                # first time on-engine
-                    if tr is not None:
-                        tr.observe("stage_seconds", t - r.arrival_s,
-                                   stage="queue")
-                        tr.request_span(r.rid, "queued", r.arrival_s, t)
-                if e.kind == "prefill" and e.last_chunk:
-                    r.first_beam_s = end            # TTFT point
-                if r.phase is Phase.DONE and r.rid not in self._results:
-                    r.finish_s = end
-                    rep.completed += 1
-                    self.router.settle(r.rid)
-                    self.counters["completed"] += 1
-                    self._tier_count(r.tier, "completed")
+            with span(tr, "serving.commit", replica=rep.index,
+                      track="scheduler"):
+                newly.extend(self._commit_step(rep, plan, timing, t,
+                                               planned.t1))
+        return newly
+
+    def _commit_step(self, rep: Replica, plan, timing: Dict[str, float],
+                     t: float, t_engine: float) -> List[ServeResult]:
+        """Phase bookkeeping and results of one engine step that started
+        at simulated time ``t`` (``t_engine`` on the wall clock)."""
+        tr = self.tracer
+        newly: List[ServeResult] = []
+        end = t + timing["critical_s"]
+        rep.busy_until = end
+        rep.dispatches += 1
+        rep.cost_model.observe(plan.token_cost, timing["critical_s"])
+        rep.policy.commit(plan)
+        for e in plan.entries:
+            r = e.req
+            if r.dispatch_s is None:
+                r.dispatch_s = t                # first time on-engine
+                if tr is not None:
+                    tr.request_dispatched(r.rid, t_engine)
+            if e.kind == "prefill" and e.last_chunk:
+                r.first_beam_s = end            # TTFT point
+            if r.phase is Phase.DONE and r.rid not in self._results:
+                r.finish_s = end
+                rep.completed += 1
+                self.router.settle(r.rid)
+                self.counters["completed"] += 1
+                self._tier_count(r.tier, "completed")
+                if r.degraded:
+                    self.counters["degraded"] += 1
+                    self._tier_count(r.tier, "degraded")
+                res = ServeResult(
+                    rid=r.rid, items=r.items, log_probs=r.log_probs,
+                    arrival_s=r.arrival_s, dispatch_s=r.dispatch_s,
+                    finish_s=end,
+                    first_beam_s=(r.first_beam_s if r.first_beam_s
+                                  is not None else end),
+                    tier=r.tier, degraded=r.degraded,
+                    served_beam_width=r.served_beam_width,
+                    served_phases=r.served_phases,
+                    timing={"queue_s": r.dispatch_s - r.arrival_s,
+                            "step_tokens": float(plan.token_cost),
+                            **timing})
+                if tr is not None:
+                    tr.count("requests_completed", tier=r.tier)
                     if r.degraded:
-                        self.counters["degraded"] += 1
-                        self._tier_count(r.tier, "degraded")
-                    res = ServeResult(
-                        rid=r.rid, items=r.items, log_probs=r.log_probs,
-                        arrival_s=r.arrival_s, dispatch_s=r.dispatch_s,
-                        finish_s=end,
-                        first_beam_s=(r.first_beam_s if r.first_beam_s
-                                      is not None else end),
-                        tier=r.tier, degraded=r.degraded,
-                        served_beam_width=r.served_beam_width,
-                        served_phases=r.served_phases,
-                        timing={"queue_s": r.dispatch_s - r.arrival_s,
-                                "step_tokens": float(plan.token_cost),
-                                **timing})
-                    if tr is not None:
-                        tr.count("requests_completed", tier=r.tier)
-                        if r.degraded:
-                            tr.count("requests_degraded", tier=r.tier)
-                        tr.request_end(r.rid, end, "completed")
-                        res.spans = tr.take_request_spans(r.rid)
-                    self._results[r.rid] = res
-                    self.completed.append(r)
-                    newly.append(res)
+                        tr.count("requests_degraded", tier=r.tier)
+                    tr.request_end(r.rid, "completed")
+                    res.spans = tr.take_request_spans(r.rid)
+                self._results[r.rid] = res
+                self.completed.append(r)
+                newly.append(res)
         return newly
 
     # ------------------------------------------------------------- internal
     def _dispatch(self, rep: Replica, plan: BatchPlan,
                   now_s: float) -> List[ServeResult]:
         # stream pick depends only on state run_batch never touches, so
-        # hoisting it above the compute keeps values identical while giving
-        # the tracer the batch's start time
+        # hoisting it above the compute keeps values identical while naming
+        # the batch's stream track
         sidx = int(np.argmin(rep.streams))
         start = max(now_s, rep.streams[sidx])
         tr = self.tracer
-        if tr is not None:
-            tr.set_time(now_s)
-        timing = rep.engine.run_batch(plan)      # real measured compute
+        with span(tr, "engine.batch", replica=rep.index,
+                  track=f"stream {sidx}", stage="step", size=plan.size,
+                  bucket_len=plan.bucket_len) as batch:
+            timing = rep.engine.run_batch(plan)  # real measured compute
         dur = timing["critical_s"]
         rep.streams[sidx] = start + dur
         rep.dispatches += 1
         rep.completed += plan.size
         rep.cost_model.observe(plan.padded_tokens, dur)
-        if tr is not None:
-            tr.span("batch", start, start + dur, replica=rep.index,
-                    track=f"stream {sidx}",
-                    args={"size": plan.size, "bucket_len": plan.bucket_len,
-                          "dispatches": timing.get("dispatches", 0)})
-            tr.observe("stage_seconds", dur, stage="step")
         out = []
         for r in plan.requests:
             r.dispatch_s = start
@@ -785,12 +791,10 @@ class ServingSystem:
                         "batch_size": float(plan.size),
                         "bucket_len": float(plan.bucket_len), **timing})
             if tr is not None:
-                tr.observe("stage_seconds", start - r.arrival_s,
-                           stage="queue")
-                tr.request_span(r.rid, "queued", r.arrival_s, start)
-                tr.request_span(r.rid, "batch", start, start + dur)
+                tr.request_dispatched(r.rid, batch.t0)
+                tr.request_span(r.rid, "batch", batch.t0, batch.t1)
                 tr.count("requests_completed", tier=r.tier)
-                tr.request_end(r.rid, r.finish_s, "completed")
+                tr.request_end(r.rid, "completed")
                 res.spans = tr.take_request_spans(r.rid)
             self._results[r.rid] = res
             self.completed.append(r)

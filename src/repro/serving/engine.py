@@ -46,6 +46,7 @@ from repro.core.kv_arena import KVArena, init_arena
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.request import BatchPlan, StepPlan
 from repro.serving.scheduler import bucket_len
+from repro.serving.telemetry import span
 
 
 @dataclasses.dataclass
@@ -208,9 +209,8 @@ class GREngine:
         self._jit_phase0 = jax.jit(self.decoder.beam_phase0)
         self._jit_phase = jax.jit(self.decoder.beam_phase_paged,
                                   static_argnames=("d",))
-        # flight recorder (ISSUE 10): None unless the serving system wires
-        # one in — every site below guards on it, so the default path runs
-        # the exact pre-telemetry code
+        # flight recorder: None unless the serving system wires one in;
+        # the spans below then only annotate the profiler's trace
         self.tracer = None
         self.trace_replica = 0
 
@@ -287,9 +287,10 @@ class GREngine:
         compiled = self._compiled.get(key)
         compile_s = 0.0
         if compiled is None:
-            t0 = time.perf_counter()
-            compiled = fn.lower(*args, **static).compile()
-            compile_s = time.perf_counter() - t0
+            with span(self.tracer, "engine.compile",
+                      replica=self.trace_replica, key=str(key)) as sp:
+                compiled = fn.lower(*args, **static).compile()
+            compile_s = sp.seconds
             self._compiled[key] = compiled
         return compiled, compile_s
 
@@ -462,86 +463,89 @@ class GREngine:
         toks[0, :e.chunk_len] = e.req.tokens[e.offset:e.offset + e.chunk_len]
         return toks, cb
 
+    def _finalize_all(self, finish) -> float:
+        """Finalize ``(req, rt)`` pairs (device -> host of the finished
+        rows) inside one ``engine.finalize`` span; returns its seconds."""
+        with span(self.tracer, "engine.finalize", replica=self.trace_replica,
+                  rid=(finish[0][0].rid if len(finish) == 1 else None),
+                  requests=len(finish)) as sp:
+            for req, rt in finish:
+                self._finalize(req, rt)
+        return sp.seconds
+
     def run_step(self, plan: StepPlan) -> Dict[str, float]:
         """Execute one mixed prefill/decode step (numerics only — phase
-        bookkeeping is the scheduler's ``commit``).  Reference sequential
-        executor: entries run one blocked dispatch at a time, so the step's
-        critical path is the sum of its sub-dispatches
-        (:class:`~repro.serving.pipeline.PipelinedEngine` is the overlapped
-        alternative)."""
+        bookkeeping is the scheduler's ``commit``) inside one
+        ``engine.step`` span.  Returns the step's timing: ``critical_s``
+        (what the step costs the serving clock), ``compile_s``,
+        ``dispatch_s``, ``finalize_s`` (and the pipelined executor's
+        ``sync_stall_s``), all from wall-clock reads that are always on."""
+        with span(self.tracer, "engine.step", replica=self.trace_replica,
+                  stage="step", entries=len(plan.entries),
+                  tokens=plan.token_cost):
+            return self._step(plan)
+
+    def _step(self, plan: StepPlan) -> Dict[str, float]:
+        """Reference sequential executor: entries run one blocked dispatch
+        at a time, so the step's critical path is the sum of its
+        sub-dispatches (:class:`~repro.serving.pipeline.PipelinedEngine`
+        is the overlapped alternative)."""
         nd = self.gr.num_decode_phases
-        device_s = compile_s = 0.0
+        device_s = compile_s = finalize_s = 0.0
         dispatches = 0
-        tr = self.tracer
-        # span cursor: each blocked call's measured duration tiles
-        # [step start, step start + device_s] on the simulated clock —
-        # exactly the window the scheduler will charge this step
-        cur = tr.time() if tr is not None else 0.0
-        step_t0 = cur
+        tr, replica = self.tracer, self.trace_replica
         for e in plan.entries:
             r = e.req
             if e.kind == "prefill":
                 rt = self._runtime(r)
                 arena = self.arena
-                toks, cb = self._stage_chunk(e)
-                MP = len(rt.table)
-                (logits, pk, pv), dt, cs = self._timed_call(
-                    ("chunk", cb, MP, arena.num_pages), self._jit_chunk,
-                    self.params, toks,
-                    np.asarray([e.offset], np.int32),
-                    np.asarray([e.chunk_len], np.int32),
-                    arena.pages_k, arena.pages_v, rt.table[None])
+                with span(tr, "engine.dispatch", replica=replica, rid=r.rid,
+                          stage="prefill", kind="chunk", width=1):
+                    toks, cb = self._stage_chunk(e)
+                    MP = len(rt.table)
+                    (logits, pk, pv), dt, cs = self._timed_call(
+                        ("chunk", cb, MP, arena.num_pages), self._jit_chunk,
+                        self.params, toks,
+                        np.asarray([e.offset], np.int32),
+                        np.asarray([e.chunk_len], np.int32),
+                        arena.pages_k, arena.pages_v, rt.table[None])
                 arena.commit_pages(pk, pv)
                 rt.shared_len = e.offset + e.chunk_len
                 device_s += dt
                 compile_s += cs
                 dispatches += 1
-                if tr is not None:
-                    tr.span("prefill_chunk", cur, cur + dt,
-                            replica=self.trace_replica, rid=r.rid,
-                            args={"offset": e.offset, "len": e.chunk_len,
-                                  "bucket": cb, "last": e.last_chunk})
-                    tr.observe("stage_seconds", dt, stage="prefill")
-                    cur += dt
                 self.stats.prompt_tokens += e.chunk_len
                 self.stats.padded_tokens += cb
                 if e.last_chunk:
                     self._cache_insert(r, rt)
-                    (rt.state, rt.parent), dt, cs = self._timed_call(
-                        ("phase0", 1), self._jit_phase0, logits)
+                    with span(tr, "engine.dispatch", replica=replica,
+                              rid=r.rid, stage="decode", kind="phase0",
+                              width=1):
+                        (rt.state, rt.parent), dt, cs = self._timed_call(
+                            ("phase0", 1), self._jit_phase0, logits)
                     device_s += dt
                     compile_s += cs
                     dispatches += 1
-                    if tr is not None:
-                        tr.span("beam_phase0", cur, cur + dt,
-                                replica=self.trace_replica, rid=r.rid)
-                        tr.observe("stage_seconds", dt, stage="decode")
-                        cur += dt
                     self._track_pool((0,))
                     if nd <= 1 or e.final:
-                        self._finalize(r, rt)
+                        finalize_s += self._finalize_all([(r, rt)])
             else:
                 rt = self._runtimes[r.rid]
                 arena = self.arena
                 d = e.decode_phase
                 MP = len(rt.table)
-                out, dt, cs = self._timed_call(
-                    ("phase", d, 1, MP, arena.num_pages),
-                    self._jit_phase, self.params, rt.state, rt.parent,
-                    rt.unshared_k, rt.unshared_v,
-                    arena.pages_k, arena.pages_v, rt.table[None],
-                    np.asarray([rt.shared_len], np.int32), d=d)
+                with span(tr, "engine.dispatch", replica=replica, rid=r.rid,
+                          stage="decode", kind="decode", width=1, phase=d):
+                    out, dt, cs = self._timed_call(
+                        ("phase", d, 1, MP, arena.num_pages),
+                        self._jit_phase, self.params, rt.state, rt.parent,
+                        rt.unshared_k, rt.unshared_v,
+                        arena.pages_k, arena.pages_v, rt.table[None],
+                        np.asarray([rt.shared_len], np.int32), d=d)
                 rt.state, rt.parent, rt.unshared_k, rt.unshared_v = out
                 device_s += dt
                 compile_s += cs
                 dispatches += 1
-                if tr is not None:
-                    tr.span("decode_phase", cur, cur + dt,
-                            replica=self.trace_replica, rid=r.rid,
-                            args={"phase": d,
-                                  "select": self.gr.beam_select})
-                    tr.observe("stage_seconds", dt, stage="decode")
-                    cur += dt
                 self._track_pool((d,))
                 self.stats.padded_tokens += self.gr.beam_width
                 self.stats.decode_groups += 1
@@ -549,14 +553,7 @@ class GREngine:
                 self.stats.decode_group_width_max = max(
                     self.stats.decode_group_width_max, 1)
                 if d == nd - 1 or e.final:
-                    self._finalize(r, rt)
-        if tr is not None:
-            tr.span("step", step_t0, step_t0 + device_s,
-                    replica=self.trace_replica,
-                    args={"entries": len(plan.entries),
-                          "dispatches": dispatches,
-                          "tokens": plan.token_cost})
-            tr.observe("stage_seconds", device_s, stage="step")
+                    finalize_s += self._finalize_all([(r, rt)])
         self.stats.batches += 1
         self.stats.dispatches += dispatches
         self.stats.device_s += device_s
@@ -564,4 +561,5 @@ class GREngine:
         self._note_arena()
         return {"device_s": device_s, "host_mask_s": 0.0,
                 "critical_s": device_s, "compile_s": compile_s,
-                "dispatches": dispatches}
+                "dispatches": dispatches, "dispatch_s": device_s,
+                "finalize_s": finalize_s}

@@ -58,6 +58,7 @@ from repro.core import xbeam
 from repro.core.item_trie import ItemTrie
 from repro.serving.engine import GREngine
 from repro.serving.request import StepPlan
+from repro.serving.telemetry import span
 
 
 def _stack_states(states) -> xbeam.BeamState:
@@ -142,10 +143,6 @@ class PipelinedEngine(GREngine):
         self._lane_pending: List[Optional[object]] = [None] * len(self._lanes)
         self._lane_rr = 0
         self._last_lane = 0
-        # flight recorder (ISSUE 10): labels mirroring sync_list 1:1 so the
-        # end-of-step barrier can be settled item by item, attributing each
-        # wait to the dispatch being awaited.  Only populated when tracing.
-        self._sync_info: List[Tuple[str, Optional[int], Optional[int]]] = []
         self._jit_group = _make_group_phase(self.decoder)
         self._jit_group0 = _make_group_phase0(self.decoder)
         # re-jit the chunk program WITHOUT the base class's buffer
@@ -170,15 +167,9 @@ class PipelinedEngine(GREngine):
         self._last_lane = i
         pending = self._lane_pending[i]
         if pending is not None:
-            tr = self.tracer
-            if tr is not None:
-                w0 = tr.now()
-                jax.block_until_ready(pending)
-                w1 = tr.now()
-                tr.span("lane_wait", w0, w1, replica=self.trace_replica,
-                        track=f"lane {i}", args={"lane": i})
-                tr.observe("stage_seconds", w1 - w0, stage="lane_wait")
-            else:
+            with span(self.tracer, "engine.lane_wait",
+                      replica=self.trace_replica, track=f"lane {i}",
+                      stage="lane_wait", lane=i):
                 jax.block_until_ready(pending)
             self._lane_pending[i] = None
         lane = self._lanes[i]
@@ -199,8 +190,6 @@ class PipelinedEngine(GREngine):
         rts = [self._runtimes[e.req.rid] for e in entries]
         G = len(rts)
         MP = max(len(rt.table) for rt in rts)
-        tr = self.tracer
-        t0 = tr.now() if tr is not None else 0.0
         if G == 1:                              # no group to fuse: direct
             rt = rts[0]
             out, _, cs = self._async_call(
@@ -229,17 +218,6 @@ class PipelinedEngine(GREngine):
                 rt.unshared_k = uks[i]
                 rt.unshared_v = uvs[i]
             sync_list.append(states[-1].tokens)
-        if tr is not None:
-            tr.skip(cs)                 # compile is off the step timeline
-            tr.span("dispatch_decode", t0, tr.now(),
-                    replica=self.trace_replica,
-                    rid=(entries[0].req.rid if G == 1 else None),
-                    args={"phase": d, "width": G,
-                          "select": self.gr.beam_select})
-            tr.observe("stage_seconds", tr.now() - t0, stage="decode")
-            self._sync_info.append(
-                (f"decode phase {d} (width {G})",
-                 entries[0].req.rid if G == 1 else None, None))
         self._track_pool((d,), requests=G)
         self.stats.padded_tokens += G * self.gr.beam_width
         self.stats.decode_groups += 1
@@ -256,34 +234,34 @@ class PipelinedEngine(GREngine):
         return out, 0.0, compile_s
 
     # -------------------------------------------------------------- step
-    def run_step(self, plan: StepPlan) -> Dict[str, float]:
+    def _step(self, plan: StepPlan) -> Dict[str, float]:
         """One mixed prefill/decode step, overlap-structured.
 
         Order: batched decode groups first (they read page state no prefill
         of a *different* request can touch), then prefill chunks chained
         through the arena pool, then ONE batched beam-phase-0 for every
-        request whose prompt completed this step; a single barrier ends the
-        step.  ``critical_s`` is the measured wall time of the whole step —
-        dispatch, host staging overlap, and barrier together."""
+        request whose prompt completed this step; then the finished rows
+        come to the host (``engine.finalize``) and ONE
+        ``block_until_ready`` over the step's outputs ends it
+        (``engine.barrier``, the step's ``sync_stall_s``).  ``critical_s``
+        is the measured wall time of the whole step, compile excluded."""
         nd = self.gr.num_decode_phases
         t_start = time.perf_counter()
         compile_s = 0.0
         dispatches = 0
         sync_list: list = []
         finish: list = []                       # (req, rt) finalized at end
-        tr = self.tracer
-        if tr is not None:
-            # rebase real time onto the simulated clock for this step: inner
-            # spans land in [t, t + critical_s], compile time skipped out
-            tr.push_clock()
-            step_t0 = tr.now()
-            self._sync_info = []
+        tr, replica = self.tracer, self.trace_replica
 
         # --- 1. cross-request batched decode: one dispatch per phase -----
         groups = plan.phase_groups()
         for d in sorted(groups):
             entries = groups[d]
-            disp, cs = self._decode_group(d, entries, sync_list)
+            G = len(entries)
+            with span(tr, "engine.dispatch", replica=replica,
+                      rid=(entries[0].req.rid if G == 1 else None),
+                      stage="decode", kind="decode", width=G, phase=d):
+                disp, cs = self._decode_group(d, entries, sync_list)
             dispatches += disp
             compile_s += cs
             ending = [e for e in entries if d == nd - 1 or e.final]
@@ -309,28 +287,22 @@ class PipelinedEngine(GREngine):
             r = e.req
             rt = self._runtime(r)
             arena = self.arena
-            c0 = tr.now() if tr is not None else 0.0
-            toks, cb = self._stage_chunk(e)
-            MP = len(rt.table)
-            out, _, cs = self._async_call(
-                ("chunk", cb, MP, arena.num_pages), self._jit_chunk,
-                self.params, toks,
-                np.asarray([e.offset], np.int32),
-                np.asarray([e.chunk_len], np.int32),
-                arena.pages_k, arena.pages_v, rt.table[None])
+            with span(tr, "engine.dispatch", replica=replica, rid=r.rid,
+                      stage="prefill", kind="chunk", width=1):
+                toks, cb = self._stage_chunk(e)
+                MP = len(rt.table)
+                out, _, cs = self._async_call(
+                    ("chunk", cb, MP, arena.num_pages), self._jit_chunk,
+                    self.params, toks,
+                    np.asarray([e.offset], np.int32),
+                    np.asarray([e.chunk_len], np.int32),
+                    arena.pages_k, arena.pages_v, rt.table[None])
             logits, pk, pv = out
             arena.commit_pages(pk, pv)          # chain: next chunk reads it
             self._lane_pending[self._last_lane] = logits   # lane in flight
             rt.shared_len = e.offset + e.chunk_len
             dispatches += 1
             compile_s += cs
-            if tr is not None:
-                tr.skip(cs)
-                tr.span("dispatch_chunk", c0, tr.now(),
-                        replica=self.trace_replica, rid=r.rid,
-                        args={"lane": self._last_lane, "offset": e.offset,
-                              "len": e.chunk_len, "last": e.last_chunk})
-                tr.observe("stage_seconds", tr.now() - c0, stage="prefill")
             self.stats.prompt_tokens += e.chunk_len
             self.stats.padded_tokens += cb
             if e.last_chunk:
@@ -341,36 +313,25 @@ class PipelinedEngine(GREngine):
                 phase0.append((r, rt, logits, e.final))
             else:
                 sync_list.append(logits)
-                if tr is not None:
-                    self._sync_info.append(
-                        (f"chunk @{e.offset}", r.rid, self._last_lane))
 
         # --- 3. one batched beam phase 0 for every finished prefill ------
         if phase0:
             G = len(phase0)
-            p0 = tr.now() if tr is not None else 0.0
-            if G == 1:
-                out, _, cs = self._async_call(("phase0", 1),
-                                              self._jit_phase0,
-                                              phase0[0][2])
-                states, parents = (out[0],), (out[1],)
-            else:
-                out, _, cs = self._async_call(
-                    ("phase0-group", G), self._jit_group0,
-                    tuple(lg for _, _, lg, _ in phase0))
-                states, parents = out
+            with span(tr, "engine.dispatch", replica=replica,
+                      rid=(phase0[0][0].rid if G == 1 else None),
+                      stage="decode", kind="phase0", width=G):
+                if G == 1:
+                    out, _, cs = self._async_call(("phase0", 1),
+                                                  self._jit_phase0,
+                                                  phase0[0][2])
+                    states, parents = (out[0],), (out[1],)
+                else:
+                    out, _, cs = self._async_call(
+                        ("phase0-group", G), self._jit_group0,
+                        tuple(lg for _, _, lg, _ in phase0))
+                    states, parents = out
             dispatches += 1
             compile_s += cs
-            if tr is not None:
-                tr.skip(cs)
-                tr.span("dispatch_phase0", p0, tr.now(),
-                        replica=self.trace_replica,
-                        rid=(phase0[0][0].rid if G == 1 else None),
-                        args={"width": G})
-                tr.observe("stage_seconds", tr.now() - p0, stage="decode")
-                self._sync_info.append(
-                    (f"phase0 (width {G})",
-                     phase0[0][0].rid if G == 1 else None, None))
             self._track_pool((0,), requests=G)
             for i, (r, rt, _, fin) in enumerate(phase0):
                 rt.state = states[i]
@@ -379,48 +340,16 @@ class PipelinedEngine(GREngine):
                     finish.append((r, rt))
             sync_list.append(states[-1].tokens)
 
-        # --- 4. end-of-step barrier + finalization -----------------------
-        t0 = time.perf_counter()
-        if tr is None:
-            for req, rt in finish:              # forces the finished rows
-                self._finalize(req, rt)
+        # --- 4. finalization, then the one end-of-step barrier -----------
+        dispatch_s = max(time.perf_counter() - t_start - compile_s, 0.0)
+        finalize_s = self._finalize_all(finish) if finish else 0.0
+        with span(tr, "engine.barrier", replica=replica, track="barrier",
+                  stage="barrier", awaited=len(sync_list)) as barrier:
             jax.block_until_ready(sync_list)
-        else:
-            # settle the SAME device values one by one instead of in one
-            # blocking call — value-identical, but each wait is attributed
-            # to the dispatch being awaited (the sync_stall_s breakdown)
-            b0 = tr.now()
-            for req, rt in finish:
-                f0 = tr.now()
-                self._finalize(req, rt)
-                tr.span("barrier_wait", f0, tr.now(),
-                        replica=self.trace_replica, rid=req.rid,
-                        args={"on": "finalize"})
-            for item, (label, rid, lane) in zip(sync_list, self._sync_info):
-                w0 = tr.now()
-                jax.block_until_ready(item)
-                tr.span("barrier_wait", w0, tr.now(),
-                        replica=self.trace_replica,
-                        track=("engine" if lane is None else f"lane {lane}"),
-                        rid=rid, args={"on": label})
-        stall = time.perf_counter() - t0
+        stall = barrier.seconds
         # compile (AOT warm) is a deploy-time cost, excluded from the step's
         # critical path exactly like the batch backends exclude it
         total = max(time.perf_counter() - t_start - compile_s, 0.0)
-        if tr is not None:
-            tr.span("barrier", b0, b0 + stall, replica=self.trace_replica,
-                    track="barrier",
-                    args={"finalized": len(finish),
-                          "awaited": len(sync_list)})
-            tr.observe("stage_seconds", stall, stage="barrier")
-            tr.span("step", step_t0, step_t0 + total,
-                    replica=self.trace_replica,
-                    args={"entries": len(plan.entries),
-                          "dispatches": dispatches,
-                          "tokens": plan.token_cost,
-                          "stall_ms": stall * 1e3})
-            tr.observe("stage_seconds", total, stage="step")
-            tr.pop_clock()
 
         self.stats.sync_stall_s += stall
         self.stats.batches += 1
@@ -430,7 +359,8 @@ class PipelinedEngine(GREngine):
         self._note_arena()
         return {"device_s": total, "host_mask_s": 0.0,
                 "critical_s": total, "compile_s": compile_s,
-                "dispatches": dispatches, "sync_stall_s": stall}
+                "dispatches": dispatches, "dispatch_s": dispatch_s,
+                "finalize_s": finalize_s, "sync_stall_s": stall}
 
 
 def make_engine(cfg: ModelConfig, gr: GRConfig, params,

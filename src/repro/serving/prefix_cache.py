@@ -51,6 +51,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from repro.core.kv_arena import KVArena
+from repro.serving.telemetry import span
 
 
 @dataclasses.dataclass
@@ -100,7 +101,7 @@ class PrefixCache:
     recency — oldest first — which is the LRU eviction order.
     """
 
-    #: flight recorder (ISSUE 10), wired through ``GREngine.set_tracer``
+    #: flight recorder, wired through ``GREngine.set_tracer``
     tracer = None
     trace_replica = 0
 
@@ -177,7 +178,7 @@ class PrefixCache:
             self.stats.hit_tokens += len(pids) * pg
         tr = self.tracer
         if tr is not None:
-            tr.instant("cache_probe", tr.now(), replica=self.trace_replica,
+            tr.instant("cache_probe", replica=self.trace_replica,
                        track="scheduler",
                        args={"probed_pages": len(keys),
                              "hit_pages": len(pids),
@@ -219,18 +220,17 @@ class PrefixCache:
         """Fault a spilled entry back into a fresh device page (the cache
         keeps the single reference ``take_pages`` returns)."""
         (pid,) = self.arena.take_pages(1)
-        self.arena.write_page(pid, e.host_k, e.host_v)
+        nb = self.arena.page_nbytes
+        with span(self.tracer, "cache.restore", replica=self.trace_replica,
+                  pid=pid, bytes=nb):
+            self.arena.write_page(pid, e.host_k, e.host_v)
         e.pid = pid
         e.host_k = e.host_v = None
-        self._host_bytes -= self.arena.page_nbytes
+        self._host_bytes -= nb
         self.stats.restores += 1
-        self.stats.restore_bytes += self.arena.page_nbytes
-        tr = self.tracer
-        if tr is not None:
-            tr.instant("cache_restore", tr.now(), replica=self.trace_replica,
-                       track="engine", args={"pid": pid,
-                                             "bytes": self.arena.page_nbytes})
-            tr.count("cache_restore_bytes", self.arena.page_nbytes)
+        self.stats.restore_bytes += nb
+        if self.tracer is not None:
+            self.tracer.count("cache_restore_bytes", nb)
 
     def _on_pressure(self, need: int) -> int:
         """Arena pressure callback: surrender up to ``need`` device pages,
@@ -256,20 +256,19 @@ class PrefixCache:
         self.stats.evictions += 1
         tr = self.tracer
         if self._make_host_room(nb):
-            e.host_k, e.host_v = self.arena.read_page(e.pid)
+            with span(tr, "cache.spill", replica=self.trace_replica,
+                      pid=e.pid, bytes=nb):
+                e.host_k, e.host_v = self.arena.read_page(e.pid)
             self._host_bytes += nb
             self.stats.spilled += 1
             self.stats.spill_bytes += nb
             if tr is not None:
-                tr.instant("cache_spill", tr.now(),
-                           replica=self.trace_replica, track="engine",
-                           args={"pid": e.pid, "bytes": nb})
                 tr.count("cache_spill_bytes", nb)
             self.arena.decref(e.pid)
             e.pid = None                     # stays lookupable, host tier
         else:
             if tr is not None:
-                tr.instant("cache_drop", tr.now(),
+                tr.instant("cache_drop",
                            replica=self.trace_replica, track="engine",
                            args={"pid": e.pid})
                 tr.count("cache_drops")

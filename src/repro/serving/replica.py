@@ -131,7 +131,7 @@ class ReplicaRouter:
         self._load[state.rid] = (tier, tokens)
         tr = self.tracer
         if tr is not None:
-            tr.instant("place", tr.time(), replica=rep.index,
+            tr.instant("place", replica=rep.index,
                        track="scheduler", rid=state.rid,
                        args={"outstanding_tokens": rep.outstanding_tokens(),
                              "queue_depth": rep.queue_depth()})

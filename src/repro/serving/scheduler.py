@@ -189,7 +189,7 @@ class TokenCapacityBatcher:
         blen = max(bucket_len(r.prompt_len, self.min_bucket) for r in batch)
         if self.tracer is not None:
             self.tracer.instant(
-                "batch_cut", now_s, replica=self.trace_replica,
+                "batch_cut", replica=self.trace_replica,
                 track="scheduler",
                 args={"size": len(batch), "bucket": blen,
                       "trigger": ("capacity" if capacity_hit else
@@ -310,7 +310,7 @@ class BucketAffinityBatcher:
         batch = [q.popleft() for _ in range(min(cap, len(q)))]
         if self.tracer is not None:
             self.tracer.instant(
-                "batch_cut", now_s, replica=self.trace_replica,
+                "batch_cut", replica=self.trace_replica,
                 track="scheduler",
                 args={"size": len(batch), "bucket": blen})
         return BatchPlan(requests=batch, bucket_len=blen, formed_s=now_s)
@@ -465,7 +465,7 @@ class ChunkedPrefillScheduler:
                     req.next_offset = skip
             self.active.append(req)
             if tr is not None:
-                tr.instant("admit", now_s, replica=self.trace_replica,
+                tr.instant("admit", replica=self.trace_replica,
                            track="scheduler", rid=req.rid,
                            args={"cached_tokens": req.cached_tokens,
                                  "waited_s": now_s - req.enqueue_s})

@@ -1,12 +1,12 @@
-"""Flight recorder (ISSUE 10): span tracing + metrics for the serving stack.
+"""Flight recorder: span tracing + metrics for the serving stack.
 
 The serving stack's wins all come from *overlap* — staged prefill
-interleaved with decode, multi-stream pipelining, batched same-phase
-decode with an end-of-step barrier — and aggregate scalars
-(``EngineStats``, ``ServerReport`` summaries) cannot show where a step's
-wall-clock actually goes.  This module is the instrument: a ``Tracer``
-that records spans, instants, per-request lifecycle events, counters,
-gauges, and histograms into a bounded ring buffer, and exports them as
+interleaved with decode, batched same-phase decode with one end-of-step
+barrier — and aggregate scalars (``EngineStats``, ``ServerReport``
+summaries) cannot show where a step's wall-clock actually goes.  This
+module is the instrument: a ``Tracer`` that records spans, instants,
+per-request lifecycle events, counters, gauges, and histograms into a
+bounded ring buffer, and exports them as
 
 * Chrome/Perfetto ``trace_event`` JSON (``to_chrome_trace`` /
   ``write_chrome_trace``) — one process per replica with one track per
@@ -17,31 +17,22 @@ gauges, and histograms into a bounded ring buffer, and exports them as
 * Prometheus text exposition (``to_prometheus``) of every counter,
   gauge, and histogram.
 
-Timestamps live on the SAME clock the serving simulation composes
-results on (``ServingSystem._now``):
-
-* scheduler-level events receive explicit simulated timestamps (the
-  system calls :meth:`Tracer.set_time` before touching a replica);
-* the sequential engine lays spans with a cumulative cursor starting at
-  the step's simulated start — each blocked call's measured duration
-  tiles ``[t, t + device_s]`` exactly, so spans never overlap the next
-  step;
-* the pipelined engine *rebases* real time onto the simulated clock:
-  :meth:`push_clock` anchors ``(sim_now, perf_counter())`` at step
-  start, :meth:`now` returns the anchored sim time minus accumulated
-  :meth:`skip` (compile time is excluded from ``critical_s``, so it is
-  excluded from the trace timeline too), and the step's last event lands
-  at ``t + critical_s``.
+One clock: every timestamp is ``time.perf_counter()`` seconds, taken
+when the event happens, the clock a wall-clock driver times requests
+with.  Every span is entered through :class:`span`, which also opens a
+``jax.profiler.TraceAnnotation`` of the same name (a no-op unless the
+profiler runs), so under ``jax.profiler.trace`` the same spans land on
+the ``/host:CPU`` plane of the device trace, beside the device's
+operations, whose ``op_name`` metadata carries the ``jax.named_scope``
+layers of the model step (``prefill_chunk``, ``decode_phase``,
+``beam_attn``, ``beam_select``, ...).
 
 Cost discipline: every public recording method begins with ``if not
-self.enabled: return`` — a disabled tracer allocates nothing, and every
-instrumentation site in the stack is additionally guarded by
-``if tracer is not None`` so tracing-off is bit-identical to the
-uninstrumented code.  Tracing-on only *reads* state and takes
-timestamps; it never adds device syncs that could change selections.
+self.enabled: return`` — a disabled tracer allocates nothing, and a
+system built without ``ServeConfig.trace`` carries no tracer at all.
+Recording only reads state and takes timestamps; it never adds a device
+sync, so tracing on and off make the same syncs and the same selections.
 """
-
-from __future__ import annotations
 
 import collections
 import json
@@ -50,10 +41,11 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 #: ring-buffer event record.
 #:   kind    -- "X" complete span | "i" instant | "b"/"e" async begin/end
-#:   ts, dur -- simulated-clock seconds (dur only for "X")
+#:   ts, dur -- ``time.perf_counter()`` seconds (dur only for "X")
 #:   replica -- replica index, or None for system-level ("serving") events
 #:   track   -- thread name within the replica ("engine", "lane 0", ...)
 #:   rid     -- request id the event belongs to (flow arrows + waterfalls)
@@ -77,8 +69,60 @@ def _labels_text(key: Tuple[Tuple[str, str], ...], extra: str = "") -> str:
     return "{" + ",".join(parts) + "}" if parts else ""
 
 
+class span:
+    """Context manager timing one region of the serving stack:
+    ``with span(tracer, name, **args) as sp: ...``.
+
+    It always enters ``jax.profiler.TraceAnnotation(name, **args)``, so
+    under the profiler the region shows on the host plane of the device
+    trace.  If ``tracer`` is enabled it also records the region as a span
+    on the wall clock (``replica``, ``track``, ``rid`` place it; every
+    other keyword is an argument of the event), plus a ``stage_seconds``
+    observation when ``stage`` is given.  With ``tracer`` None or disabled
+    it records nothing and the body runs as it would without it.  After
+    the block ``sp.t0``, ``sp.t1`` and ``sp.seconds`` hold the two
+    ``perf_counter`` reads it made, which callers use for their own
+    always-on timing."""
+
+    __slots__ = ("tracer", "name", "replica", "track", "rid", "stage",
+                 "args", "t0", "t1", "_annotation")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str, *,
+                 replica: Optional[int] = 0, track: str = "engine",
+                 rid: Any = None, stage: Optional[str] = None,
+                 **args: Any):
+        self.tracer = tracer
+        self.name = name
+        self.replica = replica
+        self.track = track
+        self.rid = rid
+        self.stage = stage
+        self.args = args
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "span":
+        self._annotation = TraceAnnotation(self.name, **self.args)
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        tr = self.tracer
+        if tr is not None and tr.enabled and exc[0] is None:
+            tr.span(self.name, self.t0, self.t1, replica=self.replica,
+                    track=self.track, rid=self.rid, args=self.args or None)
+            if self.stage is not None:
+                tr.observe("stage_seconds", self.seconds, stage=self.stage)
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
 class Tracer:
-    """Ring-buffered span/counter recorder on the serving clock."""
+    """Ring-buffered span/counter recorder on the wall clock."""
 
     def __init__(self, capacity: int = 262144, enabled: bool = True):
         self.enabled = bool(enabled)
@@ -89,51 +133,13 @@ class Tracer:
         self.counters: Dict[Tuple[str, tuple], float] = {}
         self.gauges: Dict[Tuple[str, tuple], float] = {}
         self.hists: Dict[Tuple[str, tuple], List[float]] = {}
-        self._sim_now = 0.0                 # scheduler-set simulated time
-        self._clocks: List[List[float]] = []   # [sim0, real0, skip] stack
         self._rid_spans: Dict[Any, List[Tuple[str, float, float]]] = {}
-        self._open_rids: set = set()
+        self._open_rids: Dict[Any, float] = {}     # rid -> submit time
 
-    # ---------------------------------------------------------------- clock
-
-    def set_time(self, t: float) -> None:
-        """Anchor the tracer to the simulated clock (scheduler calls this
-        before every replica step / dispatch)."""
-        if not self.enabled:
-            return
-        self._sim_now = float(t)
-
-    def time(self) -> float:
-        """Current simulated time as last set by the scheduler."""
-        return self._sim_now
-
-    def push_clock(self) -> None:
-        """Start a rebased real-time window at the current simulated time
-        (pipelined step: inner events get ``sim0 + elapsed_real - skip``)."""
-        if not self.enabled:
-            return
-        self._clocks.append([self._sim_now, time.perf_counter(), 0.0])
-
-    def pop_clock(self) -> None:
-        if not self.enabled:
-            return
-        if self._clocks:
-            self._clocks.pop()
-
-    def skip(self, seconds: float) -> None:
-        """Exclude ``seconds`` (e.g. compile time) from the rebased clock,
-        mirroring its exclusion from the step's ``critical_s``."""
-        if not self.enabled or not self._clocks or seconds <= 0.0:
-            return
-        self._clocks[-1][2] += float(seconds)
-
-    def now(self) -> float:
-        """Current trace timestamp: rebased real time inside a
-        ``push_clock`` window, the scheduler's simulated time outside."""
-        if not self._clocks:
-            return self._sim_now
-        sim0, real0, skipped = self._clocks[-1]
-        return sim0 + max(time.perf_counter() - real0 - skipped, 0.0)
+    @staticmethod
+    def now() -> float:
+        """The trace clock: ``time.perf_counter()`` seconds."""
+        return time.perf_counter()
 
     # --------------------------------------------------------------- events
 
@@ -145,10 +151,11 @@ class Tracer:
     def dropped(self) -> int:
         return self.emitted - len(self.events)
 
-    def span(self, name: str, t0: float, t1: float, *, replica: int = 0,
-             track: str = "engine", rid: Any = None,
-             args: Optional[dict] = None) -> None:
-        """Complete slice ``[t0, t1]`` on a replica track."""
+    def span(self, name: str, t0: float, t1: float, *,
+             replica: Optional[int] = 0, track: str = "engine",
+             rid: Any = None, args: Optional[dict] = None) -> None:
+        """Complete slice ``[t0, t1]`` on a replica track (the recording
+        primitive under :class:`span`)."""
         if not self.enabled:
             return
         self._emit(Event("X", name, float(t0), max(float(t1 - t0), 0.0),
@@ -157,12 +164,13 @@ class Tracer:
             self._rid_spans.setdefault(rid, []).append(
                 (name, float(t0), float(t1)))
 
-    def instant(self, name: str, ts: float, *, replica: Optional[int] = None,
+    def instant(self, name: str, *, replica: Optional[int] = None,
                 track: str = "lifecycle", rid: Any = None,
                 args: Optional[dict] = None) -> None:
+        """Point event, stamped now."""
         if not self.enabled:
             return
-        self._emit(Event("i", name, float(ts), None, replica, track, rid,
+        self._emit(Event("i", name, self.now(), None, replica, track, rid,
                          args))
 
     def request_span(self, rid: Any, name: str, t0: float,
@@ -173,22 +181,32 @@ class Tracer:
         self._rid_spans.setdefault(rid, []).append(
             (name, float(t0), float(t1)))
 
-    def request_begin(self, rid: Any, ts: float,
-                      args: Optional[dict] = None) -> None:
+    def request_begin(self, rid: Any, args: Optional[dict] = None) -> None:
         """Open the request's async lifecycle span (at submit)."""
         if not self.enabled:
             return
-        self._open_rids.add(rid)
-        self._emit(Event("b", "request", float(ts), None, None, "requests",
-                         rid, args))
+        ts = self.now()
+        self._open_rids[rid] = ts
+        self._emit(Event("b", "request", ts, None, None, "requests", rid,
+                         args))
 
-    def request_end(self, rid: Any, ts: float, status: str) -> None:
+    def request_dispatched(self, rid: Any, ts: float) -> None:
+        """The request first reached the engine at ``ts``: its ``queued``
+        waterfall span and ``stage_seconds{stage="queue"}`` run from its
+        submit to then."""
+        if not self.enabled or rid not in self._open_rids:
+            return
+        t0 = self._open_rids[rid]
+        self.request_span(rid, "queued", t0, ts)
+        self.observe("stage_seconds", ts - t0, stage="queue")
+
+    def request_end(self, rid: Any, status: str) -> None:
         """Close the request's async span with its terminal status.
         Idempotent: a rid is closed at most once (span conservation)."""
         if not self.enabled or rid not in self._open_rids:
             return
-        self._open_rids.discard(rid)
-        self._emit(Event("e", "request", float(ts), None, None, "requests",
+        del self._open_rids[rid]
+        self._emit(Event("e", "request", self.now(), None, None, "requests",
                          rid, {"status": status}))
 
     def open_requests(self) -> set:
@@ -313,7 +331,7 @@ class Tracer:
         out.sort(key=lambda r: r["ts"])
         return {"traceEvents": meta + out, "displayTimeUnit": "ms",
                 "otherData": {"dropped_events": self.dropped,
-                              "clock": "simulated-seconds"}}
+                              "clock": "time.perf_counter seconds"}}
 
     def write_chrome_trace(self, path: str) -> str:
         with open(path, "w") as f:
