@@ -17,9 +17,10 @@ Two mask-generation paths, both exercised by the serving engine:
                        with the device forward pass (paper §7), with a
                        reused workspace and sparse in-place updates for the
                        small final-step masks (paper's sparse storage);
-  * ``device_masks`` — jittable searchsorted membership, the "fully
-                       device-resident" variant of paper §9.5, used inside
-                       the graph-dispatched generate loop.
+  * ``device_masks`` — jittable: a MASK_NEG fill with 0.0 scattered at the
+                       tokens each beam's row of the child tables (below)
+                       lists, the "fully device-resident" variant of paper
+                       §9.5, used inside the device beam select.
 
 Plus the sparse *gather* path (``beam_select="sparse"``): per-level
 padded-CSR child tables built once at load time (the paper's data-structure
@@ -63,8 +64,8 @@ class ItemTrie:
         # dense first-level mask, precomputed at "model load" time
         self.dense_mask0 = np.full((vocab,), MASK_NEG, np.float32)
         self.dense_mask0[self.levels[0]] = 0.0
-        # compact keys must fit int32 end to end: the device membership path
-        # forms candidate keys up to max_parent * vocab + (vocab - 1), and a
+        # compact keys must fit int32 end to end: the device prefix walk
+        # forms keys up to max_parent * vocab + (vocab - 1), and a
         # silent clamp would turn an overflowed key into FALSE membership
         max_parent = max((len(l) for l in self.levels[:-1]), default=1)
         if max_parent * vocab + vocab >= 2**31:
@@ -98,9 +99,10 @@ class ItemTrie:
             self.child_tokens.append(tt)
             self.child_ids.append(it)
             self.max_fanout.append(F)
-        # device copies, uploaded once (paper §6.3 data-structure reuse)
+        # device copies, uploaded once (paper §6.3 data-structure reuse);
+        # the prefix walk never searches the last level
         self._dev_levels = [jnp.asarray(l.astype(np.int32))
-                            for l in self.levels]
+                            for l in self.levels[:-1]]
         self._dev_mask0 = jnp.asarray(self.dense_mask0)
         self._dev_children = [(jnp.asarray(t), jnp.asarray(i))
                               for t, i in zip(self.child_tokens,
@@ -161,9 +163,15 @@ class ItemTrie:
 
     @jax.named_scope("trie_mask")
     def device_masks(self, step: int, prefix_tokens: jax.Array) -> jax.Array:
-        """Jittable masks: prefix_tokens (R, BW, step) int32 -> (R, BW, V).
+        """Jittable masks: prefix_tokens (R, BW, step) int32 -> (R, BW, V),
+        equal to ``host_masks`` value for value.
 
-        Compact keys stay < 2^31 because parent ids are level indices."""
+        The prefix walk searches each of the ``step`` levels for the R·BW
+        beams' keys (compact keys stay < 2^31 because parent ids are level
+        indices).  Each beam's row of the level's child table then lists its
+        valid next tokens: a MASK_NEG fill gets 0.0 scattered at those
+        tokens.  Invalid prefixes read the all-padding row, and padding is
+        sent out of range and dropped, so their rows stay MASK_NEG."""
         assert step >= 1
         V = self.vocab
         pid = jnp.zeros(prefix_tokens.shape[:-1], jnp.int32)
@@ -174,12 +182,18 @@ class ItemTrie:
             idx = jnp.clip(jnp.searchsorted(level, keys), 0, level.shape[0] - 1)
             ok &= level[idx] == keys
             pid = idx.astype(jnp.int32)
-        level = self._dev_levels[step]
-        cand = pid[..., None] * V + jnp.arange(V, dtype=jnp.int32)
-        idx = jnp.clip(jnp.searchsorted(level, cand.reshape(-1)), 0,
-                       level.shape[0] - 1).reshape(cand.shape)
-        valid = (level[idx] == cand) & ok[..., None]
-        return jnp.where(valid, 0.0, MASK_NEG).astype(jnp.float32)
+        # flat, because the TPU pads a 2-D table's minor dim (F, as small as
+        # 2) to 128 lanes, and the table is a constant of every program
+        P1, F = self.child_tokens[step].shape
+        flat = jnp.asarray(self.child_tokens[step].reshape(-1))
+        row = jnp.where(ok, pid, P1 - 1)
+        toks = flat[row[..., None] * F + jnp.arange(F, dtype=jnp.int32)]
+        toks = jnp.where(toks == CHILD_PAD, V, toks)          # (R, BW, F)
+        R, BW = pid.shape
+        mask = jnp.full((R, BW, V), MASK_NEG, jnp.float32)
+        return mask.at[jnp.arange(R)[:, None, None],
+                       jnp.arange(BW)[None, :, None],
+                       toks].set(0.0, mode="drop")
 
 
 class MaskWorkspace:

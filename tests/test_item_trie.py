@@ -1,6 +1,9 @@
 """Valid-path constraint: trie masks (host + device), workspace reuse,
 padded-CSR child tables, and int32 key-overflow rejection."""
 
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -43,17 +46,67 @@ def test_host_masks_exact(trie, step):
             assert got == valid_next
 
 
+@pytest.fixture(scope="module")
+def branchy():
+    """A trie that branches at every level: half of a 40 x 9 x 6 grid of
+    tokens spread over a 512-token vocabulary, so most first tokens are
+    missing and most kept prefixes have several children."""
+    rng = np.random.default_rng(0)
+    grid = np.stack(np.meshgrid(np.arange(0, 512, 13), np.arange(0, 512, 61),
+                                np.arange(0, 512, 89), indexing="ij"),
+                    -1).reshape(-1, 3)
+    catalog = grid[rng.random(len(grid)) < 0.5]
+    return ItemTrie(catalog, 512), catalog
+
+
+def _mixed_prefixes(t, catalog, step, R, rng, per_kind=4):
+    """(R, 3 * per_kind, step) prefixes, shuffled: catalog prefixes, valid
+    (step - 1)-prefixes followed by a token the trie lacks there, and
+    prefixes whose first token the trie lacks."""
+    V = t.vocab
+    lacks0 = np.setdiff1d(np.arange(V), t.levels[0])
+    out = []
+    for _ in range(R * per_kind):
+        valid = catalog[rng.integers(len(catalog))][:step]
+        kids = t.child_tokens[step - 1][t.prefix_ids(valid[:-1])]
+        invalid = valid.copy()
+        invalid[-1] = rng.choice(np.setdiff1d(np.arange(V), kids))
+        stray = rng.integers(0, V, step)
+        stray[0] = rng.choice(lacks0)
+        out += [valid, invalid, stray]
+    out = np.asarray(out)
+    rng.shuffle(out)
+    return out.reshape(R, 3 * per_kind, step)
+
+
+@pytest.mark.parametrize("R", [1, 3])
 @pytest.mark.parametrize("step", [1, 2])
-def test_device_masks_match_host(trie, step):
-    t, catalog = trie
-    rng = np.random.default_rng(step + 10)
-    prefixes = np.concatenate([
-        catalog[rng.choice(len(catalog), 8)][:, :step],
-        rng.integers(0, 512, size=(8, step)),
-    ]).reshape(2, 8, step)
+def test_device_masks_match_host(branchy, step, R):
+    t, catalog = branchy
+    assert t.max_fanout[step] > 1
+    prefixes = _mixed_prefixes(t, catalog, step, R,
+                               np.random.default_rng(10 * step + R))
     host = t.host_masks(step, prefixes)
     dev = np.asarray(t.device_masks(step, jnp.asarray(prefixes, jnp.int32)))
-    np.testing.assert_array_equal(host == 0.0, dev == 0.0)
+    assert dev.dtype == host.dtype == np.float32
+    assert np.array_equal(host, dev)
+    live = (host == 0.0).sum(-1)
+    assert (live > 1).any() and (live == 0).any()
+    assert set(np.unique(host)) == {0.0, np.float32(MASK_NEG)}
+
+
+@pytest.mark.parametrize("step", [1, 2])
+def test_device_masks_form_no_per_candidate_keys(step):
+    """The dense mask at the served shape (one request, a 128-wide beam,
+    V = 8192) is built from the child tables: no int32 array of one key
+    per (beam, token) pair, 1,048,576 of them, is ever formed, and the
+    work stays under the ``trie_mask`` scope."""
+    t = ItemTrie(gen_catalog(2000, 8192, 3, seed=0), 8192)
+    text = jax.jit(lambda p: t.device_masks(step, p)).lower(
+        jax.ShapeDtypeStruct((1, 128, step), jnp.int32)).as_text(
+            dialect="hlo", debug_info=True)
+    assert not re.search(r"s32\[(1048576|1,128,8192)\]", text)
+    assert re.search(r'op_name="[^"]*trie_mask', text)
 
 
 def test_workspace_dense_then_sparse_consistent(trie):

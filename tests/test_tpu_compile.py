@@ -5,7 +5,9 @@ shapes and in-kernel ops that Mosaic refuses.  These tests hand the
 beam-attention kernels and one full-width decode phase to the TPU compiler
 for a ``v5e:2x2`` topology described in-process, at the widths OneRec
 serves (kv heads 12, head dim 64, beam 128 and 512, prompt 2048, 64-token
-pages), and check that the compiled program holds the Mosaic kernel.
+pages), and check that the compiled program holds the Mosaic kernel; and
+the dense trie mask over the served catalog, whose size only the TPU's
+layout shows.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and every pytest
@@ -118,3 +120,23 @@ def test_full_width_decode_phase_compiles(one_chip, monkeypatch):
     assert re.search(r'op_name="[^"]*/beam_select/trie_mask/', text)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("step", [1, 2])
+def test_trie_mask_program_stays_small(one_chip, step):
+    """The dense trie mask over the served catalog (100k items, V = 8192,
+    BW 128): every decode program holds the level's child table as a
+    constant, so a layout the TPU pads (a 2-D table's minor dim, the
+    level's max fanout of 2 or 32, to 128 lanes) grows each program by
+    tens of MB of device memory."""
+    from repro.config import GRConfig
+    from repro.configs import get_config
+    from repro.core import ItemTrie
+    from repro.data import gen_catalog
+
+    cfg, gr = get_config("onerec-0.1b"), GRConfig()
+    trie = ItemTrie(gen_catalog(gr.num_items, cfg.vocab_size,
+                                gr.num_decode_phases, seed=0), cfg.vocab_size)
+    compiled = jax.jit(lambda p: trie.device_masks(step, p)).lower(
+        _sds(one_chip, (1, gr.beam_width, step), jnp.int32)).compile()
+    assert compiled.memory_analysis().generated_code_size_in_bytes < 4e6
