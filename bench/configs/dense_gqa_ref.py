@@ -42,6 +42,45 @@ import numpy as np
 
 F32 = jnp.float32
 
+#: what this reference computes, as a configuration file names it
+ARCHITECTURE = (("attention_kind", "gqa"), ("norm_kind", "rmsnorm"),
+                ("act_kind", "swiglu"), ("rope_kind", "rope"))
+#: settings it implements at one value only, where a file gives them
+FIXED = (("family", "dense"), ("qkv_bias", False), ("rope_fraction", 1.0),
+         ("sliding_window", 0), ("length_penalty", 0.0), ("mask_neg", -1e9))
+#: keys it reads at any value: the sizes it computes with and the beam
+SIZES = frozenset((
+    "num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim", "d_ff",
+    "vocab_size", "rope_theta", "norm_eps", "tie_embeddings", "beam_width",
+    "top_k", "num_decode_phases", "num_items"))
+#: keys of the deployment, not of the model: the catalog, the serving
+#: settings, what ``correct`` holds, the longest position a request may
+#: reach (rotary embedding has none), and the select algorithms, which
+#: choose the same beam (``repro.config.GRConfig``)
+DEPLOYMENT = frozenset((
+    "name", "source", "reference", "catalog_seed", "serve", "limits",
+    "assumed", "dtype", "precision", "control_precision", "max_position",
+    "beam_select", "beam_early_term"))
+
+
+def check(m: dict) -> None:
+    """Refuse a configuration this reference does not implement: a ``kind``
+    or a setting other than its own, or any key it does not know (a
+    latent-attention or expert size, another family's setting)."""
+    for key, want in ARCHITECTURE:
+        if m[key] != want:
+            raise ValueError(f"{m['name']}: {key}={m[key]!r}; the reference "
+                             f"{m['reference']} has {want!r}")
+    for key, want in FIXED:
+        if key in m and m[key] != want:
+            raise ValueError(f"{m['name']}: {key}={m[key]!r}; the reference "
+                             f"{m['reference']} has {want!r}")
+    known = SIZES | DEPLOYMENT | {k for k, _ in ARCHITECTURE + FIXED}
+    for key in m:
+        if key not in known:
+            raise ValueError(f"{m['name']}: {key}={m[key]!r}; the reference "
+                             f"{m['reference']} does not implement it")
+
 
 def _dims(m: dict) -> Tuple:
     return (int(m["num_layers"]), int(m["d_model"]), int(m["num_heads"]),

@@ -39,6 +39,7 @@ class Request:
     status: str = ""
     items: Optional[np.ndarray] = None
     log_probs: Optional[np.ndarray] = None
+    replica: int = -1               # index of the replica it was placed on
 
 
 @dataclasses.dataclass
@@ -49,18 +50,25 @@ class Step:
     #: ("decode", prompt_len, phase)
     entries: List[Tuple]
     rids: List[int]
+    replica: int = 0                # index of the replica that ran it
 
 
 class Recorder:
     """Wraps each replica engine's ``run_step`` and ``prefix_probe`` so the
-    benchmark sees every step's plan and wall time, and every admission's
-    cache hit.  Install before the ``ServingSystem`` is built (it wires
-    ``prefix_probe`` into the scheduler)."""
+    benchmark sees every step's plan, wall time and replica, and every
+    admission's cache hit.  The scheduler holds the probe it was given
+    when the ``ServingSystem`` was built, so the wrapped probe replaces
+    it there too."""
 
-    def __init__(self, engine):
+    def __init__(self, replicas):
         self.steps: List[Step] = []
         self.probes: List[Tuple[float, int, int, int]] = []
         self.on = False
+        for rep in replicas:
+            self._wrap(rep)
+
+    def _wrap(self, rep) -> None:
+        engine, index = rep.engine, rep.index
         run_step, probe = engine.run_step, engine.prefix_probe
 
         def timed_step(plan):
@@ -74,7 +82,8 @@ class Recorder:
             t1 = clock()
             if self.on:
                 self.steps.append(Step(t0, t1, entries,
-                                       [e.req.rid for e in plan.entries]))
+                                       [e.req.rid for e in plan.entries],
+                                       index))
             return out
 
         def timed_probe(req):
@@ -85,6 +94,8 @@ class Recorder:
 
         engine.run_step = timed_step
         engine.prefix_probe = timed_probe
+        if getattr(rep.policy, "prefix_probe", None) is not None:
+            rep.policy.prefix_probe = timed_probe
 
 
 class Driver:
@@ -128,7 +139,9 @@ class Driver:
     def submit(self, prompt: np.ndarray, due: float) -> Request:
         with TraceAnnotation("bench.submit"):
             h = self.system.submit(prompt)
-        req = Request(h.rid, due, prompt, submit_t=clock())
+        owner = self.system.router.owner(h.rid)
+        req = Request(h.rid, due, prompt, submit_t=clock(),
+                      replica=owner.index if owner is not None else -1)
         self.requests[h.rid] = req
         return req
 

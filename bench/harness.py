@@ -2,10 +2,12 @@
 
 ``run_cell`` builds the world from the seed (catalog, weights, traffic),
 builds the system under test through its public entry
-(``repro.serving.make_engine`` -> ``ServingSystem``), warms up every shape
-the cell's traffic meets, measures one window on the host clock, checks a
-seeded sample of what the window served against the plain reference, and
-returns the result line.  ``bench/run.py`` adds the look for a chip.
+(``repro.serving.make_sharded_system``: one replica per chip of the cell),
+warms up every shape the cell's traffic meets on every replica, measures
+one window on the host clock, checks a seeded sample of what the window
+served against the plain reference, one request of each replica among
+them, and returns the result line.  ``bench/run.py`` adds the look for a
+chip.
 """
 
 from __future__ import annotations
@@ -103,39 +105,33 @@ def build_world(cell: spec.Cell, seed: int, seconds: float) -> World:
     return World(cfg, catalog, params, run, warm, s)
 
 
-def build_system(world: World):
-    """The system under test, with the benchmark's recorder installed."""
+def _fields(cls, c: dict) -> dict:
+    """The fields of dataclass ``cls`` that configuration ``c`` gives."""
+    return {f.name: c[f.name] for f in dataclasses.fields(cls) if f.name in c}
+
+
+def build_system(world: World, chips: int):
+    """The system under test, with the benchmark's recorder installed:
+    ``chips // serve.model_axis`` replicas behind the program's router
+    (``repro.serving.make_sharded_system``; one replica on one chip is the
+    program's unplaced single-engine system).  The model and GR settings
+    are every field of ``ModelConfig`` and ``GRConfig`` the configuration
+    file gives; its reference module's ``check`` refuses what it does not
+    implement.  Returns (system, recorder)."""
     from repro.config import GRConfig, ModelConfig, ServeConfig
     from repro.core import ItemTrie
-    from repro.serving import ServingSystem, make_engine
+    from repro.serving import make_sharded_system
 
     c = world.cfg
-    for key, want in (("attention_kind", "gqa"), ("norm_kind", "rmsnorm"),
-                      ("act_kind", "swiglu"), ("rope_kind", "rope")):
-        if c[key] != want:
-            raise ValueError(f"{c['name']}: {key}={c[key]!r}; the reference "
-                             f"{c['reference']} has {want!r}")
-    model = ModelConfig(
-        name=c["name"], family="dense", source=c["source"],
-        num_layers=c["num_layers"], d_model=c["d_model"],
-        num_heads=c["num_heads"], num_kv_heads=c["num_kv_heads"],
-        head_dim=c["head_dim"], d_ff=c["d_ff"], vocab_size=c["vocab_size"],
-        attention_kind=c["attention_kind"], rope_kind=c["rope_kind"],
-        rope_theta=c["rope_theta"], norm_kind=c["norm_kind"],
-        act_kind=c["act_kind"], norm_eps=c["norm_eps"],
-        tie_embeddings=c["tie_embeddings"], max_position=c["max_position"],
-        sliding_window=c["sliding_window"])
-    gr = GRConfig(beam_width=c["beam_width"], top_k=c["top_k"],
-                  num_decode_phases=c["num_decode_phases"],
-                  num_items=c["num_items"], tid_vocab=c["vocab_size"],
-                  beam_select=c["beam_select"],
-                  beam_early_term=c["beam_early_term"])
+    spec.reference(c).check(c)
+    model = ModelConfig(**{"family": "dense", **_fields(ModelConfig, c)})
+    gr = GRConfig(**{**_fields(GRConfig, c), "tid_vocab": c["vocab_size"]})
     serve = ServeConfig(**c["serve"])
+    serve = dataclasses.replace(serve,
+                                num_replicas=chips // serve.model_axis)
     trie = ItemTrie(world.catalog, c["vocab_size"])
-    engine = make_engine(model, gr, world.params, trie, serve)
-    rec = Recorder(engine)
-    system = ServingSystem(engine, serve)
-    return system, engine, rec
+    system = make_sharded_system(model, gr, world.params, trie, serve)
+    return system, Recorder(system.replicas)
 
 
 # ------------------------------------------------------------ warm-up
@@ -161,24 +157,45 @@ def buckets(lo: int, hi: int, page: int = 64) -> List[int]:
 
 
 def warm_shapes(driver: Driver, cell: spec.Cell, hist: traffic.HistorySampler):
-    """Meet every program shape the window can: for each prompt bucket, a
-    primer prompt whose pages then back bursts of G requests that differ
-    only in their last item.  A burst prefills in one step and decodes in
-    lock-step, so it runs beam phase 0 and each decode phase as one group
-    of width G, for every G up to ``warm_groups``."""
+    """Meet every program shape the window can, on every replica: for each
+    prompt bucket, a primer prompt whose pages then back bursts of G
+    requests that differ only in their last item.  A burst prefills in one
+    step and decodes in lock-step, so it runs beam phase 0 and each decode
+    phase as one group of width G, for every G up to ``warm_groups``.  Each
+    replica gets its own primer and bursts, placed on it by ``placed_on``,
+    so what a replica builds does not hang on the router's policy."""
     lo, hi = prompt_range(cell)
+    reps = driver.system.replicas
     for b in buckets(lo, hi):
         # 64 m + 1 tokens: the cache keeps its first m whole pages, so a
         # burst request (primer + one item) prefills 4 tokens and stays in
         # bucket b
         n = b - 127
         primer = np.concatenate([hist.history(n), hist.items(1)])[:n]
-        burst = [driver.submit(primer, clock())]
+        burst = [placed_on(driver, rep, primer) for rep in reps]
         driver.drain(burst, 600.0)
         for g in range(1, int(cell.traffic["warm_groups"]) + 1):
-            burst = [driver.submit(np.concatenate([primer, hist.items(1)]),
-                                   clock()) for _ in range(g)]
+            burst = [placed_on(driver, rep,
+                               np.concatenate([primer, hist.items(1)]))
+                     for rep in reps for _ in range(g)]
             driver.drain(burst, 600.0)
+
+
+def placed_on(driver: Driver, rep, prompt: np.ndarray) -> Request:
+    """Submit ``prompt`` to replica ``rep``: the router is offered that
+    replica alone, and its own bookkeeping places the request."""
+    router = driver.system.router
+    every = router.replicas
+    router.replicas = [rep]
+    try:
+        req = driver.submit(prompt, clock())
+    finally:
+        router.replicas = every
+    if req.replica != rep.index:
+        raise RuntimeError(f"warm-up request {req.rid} went to replica "
+                           f"{req.replica}, not {rep.index}: the router no "
+                           f"longer places among router.replicas")
+    return req
 
 
 def serve_traffic(driver: Driver, t: traffic.Traffic, seconds: float,
@@ -202,10 +219,12 @@ class Run:
     requests: List[Request]             # due in the window
     steps: List[Step]                   # window and drain
     probes: List[Tuple[float, int, int, int]]
+    primes: List[Request]               # session primes sent in set-up
     cache_hit_tokens: int               # prefix-cache hits in the window
     compiles: int                       # programs built in the window
     setup_s: float                      # process start to window open
     trace: Optional[dict] = None        # bench.trace_reduce.reduce(...)
+    layers: Optional[dict] = None       # bench.trace_layers.reduce(...)
 
     def window_steps(self) -> List[Step]:
         return [s for s in self.steps if self.t_open <= s.t0 < self.t_close]
@@ -233,7 +252,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
                                     compiles.hits)
     cfg, t = cell.config, cell.traffic
     world = build_world(cell, seed, seconds)
-    system, engine, rec = build_system(world)
+    system, rec = build_system(world, cell.chips)
     driver = Driver(system)
     hist = traffic.HistorySampler(world.catalog, world.seeds["warm"] + 7)
 
@@ -262,7 +281,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     t_open = clock() + 0.001
     tracer.arm(t_open + seconds)
     driver.hooks.append(tracer.hook)
-    hits0 = _hit_tokens(engine)
+    hits0 = hit_tokens(system)
     rec.on = True
     pauses = GcPauses()
     gc.callbacks.append(pauses)
@@ -270,7 +289,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     t_close = t_open + seconds
     gc.callbacks.remove(pauses)
     tracer.close()
-    hits1 = _hit_tokens(engine)
+    hits1 = hit_tokens(system)
     t_end = driver.drain(sent, float(t["drain_cap_s"]))
     rec.on = False
     trace_dir = tracer.stop()
@@ -285,24 +304,31 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         f"max {1e3 * max(lateness, default=0):.3f} ms; "
         f"{len(rec.steps)} engine steps; {n_built} programs built in the "
         f"window; drained {t_end - t_close:.3f} s after close")
+    if len(system.replicas) > 1:
+        per = np.bincount([r.replica for r in sent],
+                          minlength=len(system.replicas))
+        log(f"[window] requests placed per replica: {per.tolist()}")
     log_stalls(driver, rec, pauses, t_open, t_close)
 
     # ---- memory, then free the program's state for the reference
     dev = jax.devices()
     peak = max(d.memory_stats().get("peak_bytes_in_use", 0)
                if d.memory_stats() else 0 for d in dev[:cell.chips])
-    arena_grows = engine.arena.stats.grows if engine.arena else 0
+    arena_grows = sum(r.engine.arena.stats.grows for r in system.replicas
+                      if r.engine.arena)
     steps, probes = list(rec.steps), list(rec.probes)
-    del system, engine, driver, rec
+    del system, driver, rec
     gc.collect()
 
     run = Run(cfg, peaks, seconds, t_open, t_close, t_end, sent, steps,
-              probes, hits1 - hits0, n_built, setup_s)
+              probes, primes, hits1 - hits0, n_built, setup_s)
     if trace_dir is not None:
-        from bench import trace_reduce
-        run.trace = trace_reduce.reduce(trace_dir)
-        run.trace["t_open_host"] = tracer.t_start
+        t0 = clock()
+        read_trace(run, trace_dir, tracer.t_start)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        log(f"[trace] reduced in {clock() - t0:.3f} s; device s by scope: "
+            + ", ".join(f"{k} {v:.4f}" for k, v in sorted(
+                run.layers["scope_s"].items(), key=lambda kv: -kv[1])))
 
     if arena_grows:
         log(f"[window] the KV arena grew {arena_grows} times: size "
@@ -383,9 +409,20 @@ def log_stalls(driver: Driver, rec: Recorder, pauses: GcPauses,
         f"ms over {len(gc.get_objects())} objects")
 
 
-def _hit_tokens(engine) -> int:
-    c = engine.prefix_cache
-    return int(c.stats.hit_tokens) if c is not None else 0
+def hit_tokens(system) -> int:
+    """Prefix-cache hit tokens so far, summed over the replicas."""
+    return sum(int(r.engine.prefix_cache.stats.hit_tokens)
+               for r in system.replicas if r.engine.prefix_cache is not None)
+
+
+def read_trace(run: Run, trace: str, t_open_host: Optional[float]) -> None:
+    """Reduce a trace (directory or ``.xplane.pb[.gz]``) into ``run.trace``
+    (``bench/trace_reduce.py``, with ``t_open_host``, the host clock at the
+    trace's open marker) and ``run.layers`` (``bench/trace_layers.py``)."""
+    from bench import trace_layers, trace_reduce
+    run.trace = trace_reduce.reduce(trace)
+    run.trace["t_open_host"] = t_open_host
+    run.layers = trace_layers.reduce(trace)
 
 
 # ------------------------------------------------------------ the check
@@ -411,10 +448,19 @@ def check_run(world: World, cell: spec.Cell, done: List[Request],
     sample: List[Request] = []
     if done:
         longest = max(done, key=lambda r: (len(r.prompt), r.rid))
-        rest = [r for r in done if r is not longest]
-        pick = rng.choice(len(rest), size=min(n - 1, len(rest)),
-                          replace=False) if n > 1 else []
-        sample = [longest] + [rest[i] for i in sorted(pick)]
+        sample = [longest]
+        # one of every other replica's requests, so a fault on any replica
+        # reaches the comparison
+        for k in sorted({r.replica for r in done} - {longest.replica}):
+            mine = [r for r in done if r.replica == k]
+            sample.append(mine[int(rng.integers(len(mine)))])
+        taken = {r.rid for r in sample}
+        rest = [r for r in done if r.rid not in taken]
+        pick = rng.choice(len(rest), size=min(n - len(sample), len(rest)),
+                          replace=False) if n > len(sample) else []
+        sample = sorted(sample[1:] + [rest[i] for i in pick],
+                        key=lambda r: r.rid)
+        sample.insert(0, longest)
     ref = spec.reference(cfg)
     lo, hi = prompt_range(cell)
     s_max = buckets(lo, hi)[-1]

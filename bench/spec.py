@@ -11,6 +11,43 @@ per-layer metric is a file of its own, found by the name the cell gives:
 
 A new cell, configuration, traffic mix or metric is new files and new
 entries; no file here needs an edit for it.
+
+A configuration file gives every field of the program's ``ModelConfig``
+and ``GRConfig`` it sets, under the field's own name (``family`` is
+``"dense"`` unless given; ``GRConfig.tid_vocab`` is ``vocab_size``), the
+``serve`` block of ``ServeConfig`` fields, ``limits`` of ``correct``,
+``reference``, ``catalog_seed`` and ``control_precision``.  A cell's
+``chips`` over ``serve.model_axis`` is its number of replicas.
+
+A reference module (``configs/<reference>.py``) imports nothing of the
+program and provides:
+
+    check(cfg)                      raise ValueError, naming the key, for a
+                                    configuration it does not implement
+    init_params(cfg, seed32)        the weights every run serves, made on
+                                    the device from the seed
+    logprobs(params, cfg, prompt, beams, s_max, rows, precision=...)
+                                    log-softmax rows of a request's prompt
+                                    and served prefixes (``bench/harness.py``
+                                    ``check_run`` reads them)
+    step_flops(cfg, entries)        optional: FLOPs of one engine step's
+                                    entries; ``step_mfu`` counts with
+                                    ``bench/flops.py`` where it is absent
+
+A reader gets the run (``bench/harness.py`` ``Run``): the requests due in
+the window and the session ``primes`` sent in set-up (each with the
+``replica`` it was placed on), ``steps`` (each with its plan's entries,
+wall time and ``replica`` index), ``probes`` and ``cache_hit_tokens``
+(summed over the replicas), and in a traced run ``trace``
+(``bench/trace_reduce.py``: ``busy_s``, ``window_s``, ``kernel_s``,
+``mosaic_s``, device seconds by Mosaic kernel name, ``devices``,
+``breakdown``, ``t_open_host``) and ``layers`` (``bench/trace_layers.py``:
+``scope_s``, device seconds by program scope, ``devices``, and
+``host_spans``).  Device seconds there are averaged over the trace's
+``devices`` chips, while the steps are those of every replica: a time
+per step multiplies by ``devices`` and divides by ``serve.model_axis``
+(``trace_layers.replica_seconds``).  A reader with nothing to read
+returns None.
 """
 
 from __future__ import annotations

@@ -105,23 +105,27 @@ def test_host_spans_keep_the_programs_spans_clipped(tmp_path):
     assert spans["engine.dispatch"] == [1, pytest.approx(0.001)]
 
 
-def _run(trace, entries_per_step):
+def _run(layers, entries_per_step, model_axis=1):
     Step = types.SimpleNamespace
     steps = [Step(t0=1.0 + i, t1=1.5 + i, entries=e)
              for i, e in enumerate(entries_per_step)]
     steps.append(Step(t0=50.0, t1=51.0, entries=[("decode", 1, 1)]))
-    return types.SimpleNamespace(trace=dict(trace, t_open_host=1.0),
-                                 steps=steps, t_close=20.0)
+    return types.SimpleNamespace(trace={"t_open_host": 1.0}, layers=layers,
+                                 steps=steps, t_close=20.0,
+                                 cfg={"serve": {"model_axis": model_axis}})
+
+
+STEPS = [[("prefill", 1500, 0, 512, False),
+          ("prefill", 1500, 512, 512, False)],
+         [("prefill", 1500, 1024, 476, True), ("decode", 1500, 1)],
+         [("decode", 1500, 2)]]
 
 
 def test_per_layer_quantities():
     run = _run({"scope_s": {"beam_select": 0.3, "prefill_chunk": 0.2},
+                "devices": 1,
                 "host_spans": {"engine.step": [4, 0.5],
-                               "engine.barrier": [4, 0.3]}},
-               [[("prefill", 1500, 0, 512, False),
-                 ("prefill", 1500, 512, 512, False)],
-                [("prefill", 1500, 1024, 476, True), ("decode", 1500, 1)],
-                [("decode", 1500, 2)]])
+                               "engine.barrier": [4, 0.3]}}, STEPS)
     # selects: 1 last chunk + 2 decode entries; the step past the close
     # does not count
     assert tl.beam_select_ms(run) == pytest.approx(100.0)
@@ -129,8 +133,22 @@ def test_per_layer_quantities():
     assert tl.step_host_ms(run) == pytest.approx(50.0)
 
 
+@pytest.mark.parametrize("devices,model_axis,per_chip", [
+    (4, 1, 1.0), (4, 2, 2.0), (1, 1, 4.0)])
+def test_per_layer_quantities_count_every_replicas_work(devices, model_axis,
+                                                        per_chip):
+    """The scopes' seconds are averaged over the chips and the steps are
+    those of every replica: four one-chip replicas at 0.3 s each, or two
+    replicas over two chips each, read what one chip at 1.2 s reads."""
+    sec = 0.3 * per_chip
+    run = _run({"scope_s": {"beam_select": sec, "prefill_chunk": sec},
+                "devices": devices, "host_spans": {}}, STEPS, model_axis)
+    assert tl.beam_select_ms(run) == pytest.approx(400.0)
+    assert tl.prefill_chunk_ms(run) == pytest.approx(400.0)
+
+
 def test_per_layer_quantities_read_nothing_without_the_keys():
-    run = _run({"busy_s": 1.0}, [[("decode", 1500, 1)]])
+    run = _run(None, [[("decode", 1500, 1)]])
     assert tl.beam_select_ms(run) is None
     assert tl.prefill_chunk_ms(run) is None
     assert tl.step_host_ms(run) is None
@@ -147,3 +165,18 @@ def test_excerpt_interval_matches_trace_reduce():
     assert (t1 - t0) * 1e-9 == pytest.approx(r["window_s"])
     assert tl.scope_seconds(ops, t0, t1) == {}
     assert tl.reduce(EXCERPT)["host_spans"]["engine.run_step"][0] > 0
+
+
+def test_a_traced_run_keeps_the_layers():
+    """The harness reduces a run's trace into ``run.trace`` and
+    ``run.layers``, as a reader finds them."""
+    from bench import harness
+    run = harness.Run({}, {}, 1.0, 0.0, 1.0, 1.0, [], [], [], [], 0, 0,
+                      0.0)
+    harness.read_trace(run, EXCERPT, 12.5)
+    assert run.trace["t_open_host"] == 12.5
+    assert run.trace["mosaic_s"]["arena_beam_attention_kernel"] > 0
+    assert set(run.layers) == {"scope_s", "devices", "host_spans"}
+    assert run.layers["devices"] == run.trace["devices"] == 1
+    assert run.layers["host_spans"]["engine.run_step"][0] > 0
+    assert run.layers == tl.reduce(EXCERPT)

@@ -82,3 +82,14 @@ def test_short_names():
                         "{3,2,1,0} custom-call(s32[1] %a), custom_call_targ"
                         "et=\"tpu_custom_call\"")
     assert not tr.is_kernel("%fusion.2 = f32[512,768] fusion(%p)")
+
+
+def test_mosaic_seconds_by_kernel_name(data):
+    ops, _, r = data
+    # the excerpt's one Mosaic kernel, under the name it was given
+    assert list(r["mosaic_s"]) == ["arena_beam_attention_kernel"]
+    assert r["mosaic_s"]["arena_beam_attention_kernel"] == pytest.approx(
+        r["kernel_s"])
+    assert tr.kernel_name("%arena_beam_attention_kernel.5 = f32[1,12] "
+                          "custom-call()") == "arena_beam_attention_kernel"
+    assert tr.kernel_name("%my_kernel = f32[8] custom-call()") == "my_kernel"

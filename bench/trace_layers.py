@@ -24,8 +24,9 @@ operations inside them and are skipped, as in ``trace_reduce``.
 
     python3 bench/trace_layers.py <trace dir or .xplane.pb[.gz]>
 
-prints ``{"scope_s": ..., "host_spans": ...}`` as JSON.  The benchmark's
-harness does not call this yet (``PERF.md`` §7 names the edit).
+prints ``{"scope_s": ..., "devices": ..., "host_spans": ...}`` as JSON.
+A traced run of the benchmark keeps the same as ``run.layers``
+(``bench/harness.py``).
 """
 
 from __future__ import annotations
@@ -220,57 +221,67 @@ def host_spans(host: List[Event], t0: int, t1: int,
 
 
 def reduce(trace: str) -> dict:
-    """``scope_s`` and ``host_spans`` of a trace (directory or file)."""
+    """``scope_s``, the number of device planes (``devices``) and
+    ``host_spans`` of a trace (directory or file)."""
     space = load(trace)
     ops = device_ops(space)
     if not ops:
         raise ValueError(f"{trace}: no {trace_reduce.DEVICE_PREFIX}* plane")
     host = host_events(space)
     t0, t1 = interval(host, ops)
-    return {"scope_s": scope_seconds(ops, t0, t1),
+    return {"scope_s": scope_seconds(ops, t0, t1), "devices": len(ops),
             "host_spans": host_spans(host, t0, t1)}
 
 
 # ------------------------------------------------- per-layer quantities
-# Each reads ``run.trace`` (the harness's reduction, with the keys of
-# ``reduce`` above added) and the engine steps lying wholly inside the
-# traced interval, counted as ``bench/metrics/beam_attn_roofline.py``
-# counts them.  None when there is nothing to read.
+# Each reads ``run.layers`` (``reduce`` above, of the run's trace) and the
+# engine steps lying wholly inside the traced interval (from
+# ``run.trace["t_open_host"]``), counted as
+# ``bench/metrics/beam_attn_roofline.py`` counts them.  None when there is
+# nothing to read.
 
 def traced_steps(run) -> list:
-    tr = run.trace
     return [s for s in run.steps
-            if tr["t_open_host"] <= s.t0 and s.t1 <= run.t_close]
+            if run.trace["t_open_host"] <= s.t0 and s.t1 <= run.t_close]
+
+
+def replica_seconds(run, seconds: float, devices: int) -> float:
+    """Device ``seconds`` averaged over a trace's ``devices`` chips, as the
+    seconds the replicas' steps took: summed over the chips, and each step
+    counted once over the ``serve.model_axis`` chips its replica spans.
+    The steps of every replica are counted, so a time per step divides
+    this.  One replica on one chip reads ``seconds``."""
+    return seconds * devices / int(run.cfg["serve"].get("model_axis", 1))
 
 
 def beam_select_ms(run) -> Optional[float]:
     """Device ms of the ``beam_select`` scope per request-phase selected
     (decode entries + last prefill chunks)."""
-    tr = run.trace
-    if not tr or "scope_s" not in tr:
+    if not run.layers:
         return None
     n = sum(1 for s in traced_steps(run) for e in s.entries
             if e[0] == "decode" or (e[0] == "prefill" and e[4]))
-    sec = tr["scope_s"].get("beam_select", 0.0)
+    sec = replica_seconds(run, run.layers["scope_s"].get("beam_select", 0.0),
+                          run.layers["devices"])
     return 1e3 * sec / n if n and sec > 0 else None
 
 
 def prefill_chunk_ms(run) -> Optional[float]:
     """Device ms of the ``prefill_chunk`` scope per prefill chunk."""
-    tr = run.trace
-    if not tr or "scope_s" not in tr:
+    if not run.layers:
         return None
     n = sum(1 for s in traced_steps(run) for e in s.entries
             if e[0] == "prefill")
-    sec = tr["scope_s"].get("prefill_chunk", 0.0)
+    sec = replica_seconds(run,
+                          run.layers["scope_s"].get("prefill_chunk", 0.0),
+                          run.layers["devices"])
     return 1e3 * sec / n if n and sec > 0 else None
 
 
 def step_host_ms(run) -> Optional[float]:
     """Host ms per engine step outside its end-of-step wait:
     (``engine.step`` s - ``engine.barrier`` s) / ``engine.step`` count."""
-    tr = run.trace
-    spans = tr.get("host_spans") if tr else None
+    spans = run.layers["host_spans"] if run.layers else None
     if not spans or "engine.step" not in spans:
         return None
     n, step_s = spans["engine.step"]

@@ -15,7 +15,9 @@ The beam-attention kernel is the Mosaic custom call
 after its jitted wrapper ``arena_beam_attention_kernel``.  The continuous
 path holds no other Mosaic call: the other custom calls in its trace are
 XLA's own ``AllocateBuffer`` and ``ConcatBitcast`` (a 33 s trace of
-``onerec-0.1b.steady``, looked at by hand).
+``onerec-0.1b.steady``, looked at by hand).  ``mosaic_s`` gives the
+device seconds of every Mosaic call by the kernel's name, so a reader of
+another kernel needs no edit here.
 """
 
 from __future__ import annotations
@@ -25,6 +27,7 @@ import collections
 import glob
 import heapq
 import os
+import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 DEVICE_PREFIX = "/device:TPU:"
@@ -108,6 +111,13 @@ def is_kernel(name: str) -> bool:
     return name.startswith(KERNEL_PREFIXES) and MOSAIC in name
 
 
+def kernel_name(name: str) -> str:
+    """``%arena_beam_attention_kernel.5 = ...`` -> the name the kernel was
+    given, ``arena_beam_attention_kernel``: the instruction without its
+    ``%`` and the number XLA appends to make it unique."""
+    return re.sub(r"\.\d+$", "", name.partition(" = ")[0].lstrip("%"))
+
+
 def reduce(trace: str, top: int = 10) -> dict:
     """``trace`` is a trace directory or an ``.xplane.pb`` file."""
     path = trace if os.path.isfile(trace) else xplane_file(trace)
@@ -127,6 +137,7 @@ def reduce(trace: str, top: int = 10) -> dict:
     t1 = closes[-1] if closes else last
     window_s = (t1 - t0) * 1e-9
     busy, kernel = [], []
+    mosaic: Dict[str, float] = collections.Counter()
     ops: Dict[str, float] = collections.Counter()
     gaps: Dict[str, float] = collections.Counter()
     holes = []
@@ -135,6 +146,9 @@ def reduce(trace: str, top: int = 10) -> dict:
         spans = union([(a, b) for _, a, b in evs])
         busy.append(sum(b - a for a, b in spans) * 1e-9)
         kernel.append(sum(b - a for n, a, b in evs if is_kernel(n)) * 1e-9)
+        for n, a, b in evs:
+            if MOSAIC in n:
+                mosaic[kernel_name(n)] += (b - a) * 1e-9
         starts = [a for _, a, _ in mods]
         for n, a, b in evs:
             if _container(n):
@@ -152,8 +166,10 @@ def reduce(trace: str, top: int = 10) -> dict:
     n = len(per_dev)
     return {
         "window_s": window_s,
+        "devices": n,
         "busy_s": sum(busy) / n,
         "kernel_s": sum(kernel) / n,
+        "mosaic_s": {k: v / n for k, v in sorted(mosaic.items())},
         "kernel_events": sum(1 for evs in per_dev for e in _clip(evs, t0, t1)
                              if is_kernel(e[0])) // n,
         "breakdown": {
